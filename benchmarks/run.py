@@ -129,6 +129,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.graphs import (
     DATASETS, edges_to_csr, load_dataset, normalize_edge_updates,
 )
@@ -784,120 +785,128 @@ def fig_serve(*, pool_size: int = 8, scale: int = 7, edge_factor: int = 6,
           f"shed={snap['counters'].get('shed', 0)}")
 
 
-# Runs under forced host devices in a subprocess (jax locks the device count
-# at first init, so the parent cannot shard itself). argv: ndev scale
-# edge_factor iters. Prints one ``ROWS:<json>`` line.
-_DIST_SCRIPT = r"""
-import os, sys
-ndev, scale, ef, iters = (int(a) for a in sys.argv[1:5])
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=%d" % ndev)
-import json, time
-import jax
-from repro.graphs.generators import rmat_graph
-from repro.launch.mesh import make_mesh
-from repro.core import triangle_count_scipy
-from repro.core import engine
-from repro.core.engine import plan_triangle_count, executable_cache_info
-from repro.graphs.device import ShardedDeviceCSR
+def _dist_rows(ndev: int, scale: int, edge_factor: int, iters: int) -> list:
+    """The ``fig_dist`` rows over a 1-D mesh of this process's ``ndev``
+    devices; every row oracle-asserted."""
+    from repro.launch.mesh import make_mesh
+    from repro.core import engine
+    from repro.core.engine import plan_triangle_count
+    from repro.graphs.device import ShardedDeviceCSR
 
-assert jax.device_count() == ndev, jax.device_count()
-g = rmat_graph(scale, ef, seed=11, name="dist%d" % scale)
-want = int(triangle_count_scipy(g))
-mesh = make_mesh((ndev,), ("data",))
-rows = []
+    assert jax.device_count() == ndev, jax.device_count()
+    g = rmat_graph(scale, edge_factor, seed=11, name="dist%d" % scale)
+    want = int(triangle_count_scipy(g))
+    mesh = make_mesh((ndev,), ("data",))
+    rows = []
 
+    def best(fn):
+        b = float("inf")
+        for _ in range(iters):
+            t0 = time.perf_counter()
+            fn()
+            b = min(b, time.perf_counter() - t0)
+        return b * 1e6
 
-def best(fn):
-    b = float("inf")
-    for _ in range(iters):
+    # single-device reference: the warm planned intersection lane
+    t0 = time.perf_counter()
+    p1 = plan_triangle_count(g, "intersection")
+    assert p1.count() == want
+    prep1 = (time.perf_counter() - t0) * 1e6
+    rows.append(dict(name="rmat%d_single" % scale, prep_us=prep1,
+                     count_us=best(p1.count), derived="devices=1;oracle=ok"))
+
+    # the pre-engine one-shot shard_map lane, reconstructed honestly: full
+    # prep, the deal, and a FRESH jitted closure on EVERY call — nothing
+    # shared with the executable cache (what core/distributed.py did before
+    # the planned lanes)
+    def one_shot():
+        sh = ShardedDeviceCSR.from_graph(g, mesh)
+        total = 0
+        for b in sh.buckets:
+            strat, bits = engine._resolve_bucket_strategy(
+                b.width, g.n + 2, "auto", None)
+            fn = engine._build_dist_intersect_executable(
+                strat, bits, b.shape + (b.chunk,), mesh)
+            total += int(fn(b.u_lists, b.v_lists, b.valid))
+        return total
+
+    us_os = float("inf")
+    for _ in range(max(2, iters)):  # every call pays prep + trace + compile
         t0 = time.perf_counter()
-        fn()
-        b = min(b, time.perf_counter() - t0)
-    return b * 1e6
+        assert one_shot() == want
+        us_os = min(us_os, (time.perf_counter() - t0) * 1e6)
+    rows.append(dict(name="rmat%d_oneshot%d" % (scale, ndev), prep_us=0.0,
+                     count_us=us_os,
+                     derived="devices=%d;oracle=ok;cached=no" % ndev))
+
+    # the planned distributed lanes: prep once, cached per-shard executables,
+    # zero recompiles across the timed replays
+    for lane, tag in (("intersection_distributed", "planned"),
+                      ("matrix_distributed", "matrix")):
+        t0 = time.perf_counter()
+        p = plan_triangle_count(g, lane, mesh=mesh)
+        assert p.count() == want
+        prep_us = (time.perf_counter() - t0) * 1e6
+        m0 = executable_cache_info()["misses"]
+        us = best(p.count)
+        rec = executable_cache_info()["misses"] - m0
+        assert rec == 0, (lane, rec)
+        work = p.meta["shard_work"]
+        balance = max(work) / max(min(work), 1)
+        rows.append(dict(
+            name="rmat%d_%s%d" % (scale, tag, ndev), prep_us=prep_us,
+            count_us=us,
+            derived="devices=%d;oracle=ok;recompiles=%d;speedup=%.2fx;"
+                    "balance=%.2f" % (ndev, rec, us_os / us, balance)))
+
+    return rows
 
 
-# single-device reference: the warm planned intersection lane
-t0 = time.perf_counter()
-p1 = plan_triangle_count(g, "intersection")
-assert p1.count() == want
-prep1 = (time.perf_counter() - t0) * 1e6
-rows.append(dict(name="rmat%d_single" % scale, prep_us=prep1,
-                 count_us=best(p1.count), derived="devices=1;oracle=ok"))
-
-
-# the pre-engine one-shot shard_map lane, reconstructed honestly: full
-# prep, the deal, and a FRESH jitted closure on EVERY call — nothing
-# shared with the executable cache (what core/distributed.py did before
-# the planned lanes)
-def one_shot():
-    sh = ShardedDeviceCSR.from_graph(g, mesh)
-    total = 0
-    for b in sh.buckets:
-        strat, bits = engine._resolve_bucket_strategy(
-            b.width, g.n + 2, "auto", None)
-        fn = engine._build_dist_intersect_executable(
-            strat, bits, b.shape + (b.chunk,), mesh)
-        total += int(fn(b.u_lists, b.v_lists, b.valid))
-    return total
-
-
-us_os = float("inf")
-for _ in range(max(2, iters)):  # every call pays prep + trace + compile
-    t0 = time.perf_counter()
-    assert one_shot() == want
-    us_os = min(us_os, (time.perf_counter() - t0) * 1e6)
-rows.append(dict(name="rmat%d_oneshot%d" % (scale, ndev), prep_us=0.0,
-                 count_us=us_os,
-                 derived="devices=%d;oracle=ok;cached=no" % ndev))
-
-# the planned distributed lanes: prep once, cached per-shard executables,
-# zero recompiles across the timed replays
-for lane, tag in (("intersection_distributed", "planned"),
-                  ("matrix_distributed", "matrix")):
-    t0 = time.perf_counter()
-    p = plan_triangle_count(g, lane, mesh=mesh)
-    assert p.count() == want
-    prep_us = (time.perf_counter() - t0) * 1e6
-    m0 = executable_cache_info()["misses"]
-    us = best(p.count)
-    rec = executable_cache_info()["misses"] - m0
-    assert rec == 0, (lane, rec)
-    work = p.meta["shard_work"]
-    balance = max(work) / max(min(work), 1)
-    rows.append(dict(
-        name="rmat%d_%s%d" % (scale, tag, ndev), prep_us=prep_us,
-        count_us=us,
-        derived="devices=%d;oracle=ok;recompiles=%d;speedup=%.2fx;"
-                "balance=%.2f" % (ndev, rec, us_os / us, balance)))
-
-print("ROWS:" + json.dumps(rows), flush=True)
+# CPU runs only: forced host devices in a child that sets its own platform
+# (jax locks the device count at first init, so the parent cannot shard
+# itself). argv: ndev scale edge_factor iters benchmarks_dir. Prints one
+# ``ROWS:<json>`` line.
+_DIST_CHILD = r"""
+import json, os, sys
+ndev, scale, ef, iters = (int(a) for a in sys.argv[1:5])
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d" % ndev
+sys.path.insert(0, sys.argv[5])
+import run
+print("ROWS:" + json.dumps(run._dist_rows(ndev, scale, ef, iters)), flush=True)
 """
 
 
 def fig_dist(*, ndev: int = 8, scale: int = 8, edge_factor: int = 8,
              iters: int = 3, min_speedup: float = 0.0) -> None:
-    """Single device vs ``ndev`` forced host devices (see ``_DIST_SCRIPT``).
+    """Single device vs a mesh of devices.
 
-    The subprocess asserts every row against the scipy oracle and asserts
-    zero recompiles across the planned lanes' timed replays; the parent
-    re-emits its rows and gates the planned-vs-one-shot speedup at
-    ``min_speedup`` when non-zero.
+    On the CPU the mesh is ``ndev`` forced host devices in a child process
+    (``_DIST_CHILD``); on an accelerator it is every device this process
+    holds, in-process, since a child could not reach a device the parent
+    already holds. Every row asserts the scipy oracle and zero recompiles
+    across the planned lanes' timed replays; the rows are re-emitted here and
+    the planned-vs-one-shot speedup is gated at ``min_speedup`` when
+    non-zero.
     """
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)  # the script forces its own device count
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-c", _DIST_SCRIPT, str(ndev), str(scale),
-         str(edge_factor), str(iters)],
-        env=env, capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = [l for l in proc.stdout.splitlines() if l.startswith("ROWS:")]
-    assert lines, proc.stdout
+    if jax.default_backend() != "cpu":
+        ndev = jax.device_count()
+        rows = _dist_rows(ndev, scale, edge_factor, iters)
+    else:
+        env = dict(os.environ)
+        here = os.path.dirname(os.path.abspath(__file__))
+        env["PYTHONPATH"] = os.path.join(here, "..", "src") + os.pathsep \
+            + env.get("PYTHONPATH", "")
+        proc = subprocess.run(
+            [sys.executable, "-c", _DIST_CHILD, str(ndev), str(scale),
+             str(edge_factor), str(iters), here],
+            env=env, capture_output=True, text=True, timeout=900)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        lines = [l for l in proc.stdout.splitlines() if l.startswith("ROWS:")]
+        assert lines, proc.stdout
+        rows = json.loads(lines[0][len("ROWS:"):])
     speedup = None
-    for r in json.loads(lines[0][len("ROWS:"):]):
+    for r in rows:
         assert "oracle=ok" in r["derived"], r
         _emit("fig_dist_" + r["name"], r["prep_us"], r["count_us"],
               r["derived"])
@@ -988,6 +997,7 @@ def _parse_figures(spec: str):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--figures", default=None,
                     help=f"comma list from {{{','.join(_FIGURES)}}}")

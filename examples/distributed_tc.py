@@ -13,6 +13,7 @@ import time  # noqa: E402
 
 import jax  # noqa: E402
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.graphs import rmat_graph  # noqa: E402
 from repro.core import (  # noqa: E402
@@ -21,6 +22,7 @@ from repro.core import (  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     print(f"devices: {jax.device_count()} × {jax.devices()[0].platform}")
     mesh = make_mesh((4, 2), ("data", "model"))
     g = rmat_graph(12, 8, seed=3)

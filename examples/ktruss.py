@@ -4,11 +4,13 @@ applications of triangle enumeration (§1), all routed through the session.
     PYTHONPATH=src python examples/ktruss.py
 """
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.graphs import rmat_graph, watts_strogatz_graph
 from repro.core import TriangleCounter
 
 
 def main():
+    enable_compile_cache()
     for g in (rmat_graph(10, 8, seed=4), watts_strogatz_graph(2000, 8, 0.05)):
         # one session: clustering metrics replay the cached vertex
         # executables, edge_support/k_truss the cached edge executables and

@@ -8,6 +8,7 @@ then amortize repeated counts through the session's cached plan.
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.graphs import complete_graph, grid_graph, rmat_graph
 from repro.core import (
     CountOptions,
@@ -17,6 +18,7 @@ from repro.core import (
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=10)
     args = ap.parse_args()

@@ -11,11 +11,13 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_model, get_reduced_config
 from repro.train.serve_step import greedy_generate
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--batch", type=int, default=4)

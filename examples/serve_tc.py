@@ -9,12 +9,14 @@ demonstration, ending with the latency/coalesce/shed summary.
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import CountOptions
 from repro.graphs import rmat_graph
 from repro.serve import RequestShed, ServeConfig, TriangleService
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", type=int, default=4)
     ap.add_argument("--requests", type=int, default=32)

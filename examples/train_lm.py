@@ -15,6 +15,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_model, get_reduced_config
 from repro.train.data import SyntheticDataConfig, SyntheticDataset
 from repro.train.elastic import ElasticTrainer, Heartbeat
@@ -35,6 +36,7 @@ def build_cfg(preset: str):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="tiny", choices=["tiny", "100m"])
     ap.add_argument("--steps", type=int, default=300)

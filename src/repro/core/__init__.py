@@ -46,8 +46,9 @@ Public API:
     graph_fingerprint — stable CSR content hash; with
         ``CountOptions.key()`` it forms ``CounterSession.session_key()``,
         the serving layer's session-reuse identity
-    DEFAULT_INTERPRET / resolve_interpret — the single interpret-mode default
-        (``TC_INTERPRET`` env var)
+    resolve_interpret / KernelPlatformError — the Pallas interpret mode,
+        resolved at plan time from the platform (interpreted on the CPU,
+        compiled on a TPU)
     enumerate_triangles — host-side triangle enumeration
     k_truss / edge_support — DEPRECATED shims over the retained numpy parity
         oracle; use the ``TriangleCounter`` methods
@@ -60,8 +61,8 @@ Public API:
 from repro.core.options import (
     CHOOSERS,
     CountOptions,
-    DEFAULT_INTERPRET,
     DEFAULT_WIDTHS,
+    KernelPlatformError,
     resolve_interpret,
 )
 from repro.core.registry import (
@@ -151,8 +152,8 @@ __all__ = [
     "EdgeUpdate",
     "GraphTooLargeError",
     "normalize_edge_updates",
-    "DEFAULT_INTERPRET",
     "DEFAULT_WIDTHS",
+    "KernelPlatformError",
     "resolve_interpret",
     "register_algorithm",
     "available_algorithms",

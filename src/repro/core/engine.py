@@ -78,10 +78,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:  # jax ≥ 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax ships it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.graphs.formats import (
     Graph,
@@ -629,7 +626,7 @@ def _build_dist_intersect_executable(strategy: str,
 
         return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=PartitionSpec(),
-                         check_rep=False)(u, v, valid)
+                         check_vma=False)(u, v, valid)
 
     return run
 
@@ -664,7 +661,7 @@ def _build_dist_matrix_executable(shape_key: tuple, mesh) -> Callable:
 
         return shard_map(local, mesh=mesh, in_specs=(spec,) * 4,
                          out_specs=PartitionSpec(),
-                         check_rep=False)(l, u, a, valid)
+                         check_vma=False)(l, u, a, valid)
 
     return run
 
@@ -690,7 +687,7 @@ def _build_dist_edge_executable(strategy: str, bitmap_bits: Optional[int],
         return shard_map(
             local, mesh=mesh,
             in_specs=(spec, spec, spec, spec, PartitionSpec()),
-            out_specs=PartitionSpec(), check_rep=False,
+            out_specs=PartitionSpec(), check_vma=False,
         )(u_lists, v_lists, src, dst, row_ptr)
 
     return run
@@ -902,13 +899,15 @@ class _TiledStage:
     """A bucket too large for the ``max_device_bytes`` budget, streamed
     through ONE cached chunk-shaped executable instead of held resident.
 
-    The bucket's padded arrays stay in host memory; ``run()`` uploads
-    ``chunk_rows`` rows at a time (tail chunks padded with the repo-wide
-    inert row fills) and accumulates the partial counts on host. Chunk rows
-    are a pow2 class ≤ the bucket extent, so every chunk of every
-    same-width bucket under the same budget shares a single executable —
-    zero steady-state recompiles, cache-stats-asserted in
-    ``tests/test_tiled.py`` — and the count is bit-identical to the
+    The bucket's rows come either from padded host arrays (``host_args``,
+    uploaded ``chunk_rows`` rows at a time, tail chunks padded with the
+    repo-wide inert row fills) or from a device-resident
+    ``prep.StreamedBucket`` (``source``), which gathers each chunk on device
+    so the whole bucket is never materialized anywhere. Partial counts
+    accumulate on host. Chunk rows are a pow2 class ≤ the bucket extent, so
+    every chunk of every same-width bucket under the same budget shares a
+    single executable — zero steady-state recompiles, cache-stats-asserted
+    in ``tests/test_tiled.py`` — and the count is bit-identical to the
     monolithic path (integer partials; the matrix lane's float partials are
     exact integers far below 2^24).
     """
@@ -925,9 +924,12 @@ class _TiledStage:
     vertex_args: Optional[Tuple[np.ndarray, np.ndarray]] = None
     float_acc: bool = False  # matrix lane accumulates float partials
     args: Tuple = ()  # no resident device buffers (block_until_ready no-op)
+    source: Optional[prep.StreamedBucket] = None  # device-gathered rows
 
     @property
     def rows(self) -> int:
+        if self.source is not None:
+            return self.source.e_pad
         return int(self.host_args[0].shape[0])
 
     @property
@@ -951,6 +953,10 @@ class _TiledStage:
     def run(self):
         """Stream every chunk through the cached executable; host-side
         accumulation of the partial counts."""
+        if self.source is not None:
+            return sum(int(self.source.count(self.executable, s,
+                                             self.chunk_rows))
+                       for s in range(0, self.rows, self.chunk_rows))
         total = 0.0 if self.float_acc else 0
         for chunk_args in self._iter_chunks(self.host_args, self.fills):
             r = self.executable(*chunk_args)
@@ -959,6 +965,9 @@ class _TiledStage:
 
     def iter_vertex_chunks(self):
         """Chunked (u, v, src, dst) uploads for the per-vertex path."""
+        if self.source is not None:
+            return (self.source.gather(s, self.chunk_rows)
+                    for s in range(0, self.rows, self.chunk_rows))
         assert self.vertex_args is not None
         return self._iter_chunks(self.host_args + tuple(self.vertex_args),
                                  self.fills + (0, 0))
@@ -1038,7 +1047,9 @@ class TrianglePlan:
         """
         if self.algorithm not in ("intersection", "subgraph", "bfs") \
                 or self.divisor != 1 \
-                or any(st.vertex_args is None for st in self.stages):
+                or any(st.vertex_args is None
+                       and getattr(st, "source", None) is None
+                       for st in self.stages):
             raise NotImplementedError(
                 f"per-vertex counts need filtered-intersection stages; "
                 f"algorithm={self.algorithm!r} divisor={self.divisor} does "
@@ -1080,10 +1091,13 @@ class TrianglePlan:
 
 
 def _resolve_bucket_strategy(width: int, id_range: int, strategy: str,
-                             bitmap_bits: Optional[int]):
-    """Resolve one bucket's (strategy, bitmap_bits), honoring a forced
-    ``bitmap_bits`` override (which must cover the id range)."""
-    strat, bits = resolve_strategy(width, id_range, strategy=strategy)
+                             bitmap_bits: Optional[int],
+                             backend: str = "jnp"):
+    """Resolve one bucket's (strategy, bitmap_bits) for the core ``backend``
+    runs, honoring a forced ``bitmap_bits`` override (which must cover the
+    id range)."""
+    strat, bits = resolve_strategy(width, id_range, strategy=strategy,
+                                   backend=backend)
     if bitmap_bits is not None and strat == "bitmap":
         if bitmap_bits < id_range:
             raise ValueError(
@@ -1097,12 +1111,16 @@ def _resolve_bucket_strategy(width: int, id_range: int, strategy: str,
 
 def _buckets_for_plan(g, variant: str, widths: Sequence[int],
                       prep_backend: str, policy: Optional[ShapePolicy],
+                      max_bucket_bytes: Optional[int] = None,
                       ) -> List[DeviceBucket]:
     """Run the prep stage on the requested backend; either way the result is
-    device-resident ``DeviceBucket``s (the host path uploads its arrays)."""
+    device-resident ``DeviceBucket``s (the host path uploads its arrays).
+    The device path returns a ``prep.StreamedBucket`` for any bucket past
+    ``max_bucket_bytes``."""
     if prep_backend == "device":
         return prep.prepare_intersection_buckets_device(
             g, variant=variant, widths=widths, policy=policy,
+            max_bucket_bytes=max_bucket_bytes,
         )
     host = prep.prepare_intersection_buckets_host(g, variant=variant,
                                                   widths=widths)
@@ -1116,10 +1134,21 @@ def _buckets_for_plan(g, variant: str, widths: Sequence[int],
     ]
 
 
-def _bucket_nbytes(e_pad: int, width: int) -> int:
-    """Device bytes one resident intersection bucket costs: the (e, w)
-    int32 u/v neighbor-list pair plus the (e,) int32 src/dst endpoints."""
-    return int(e_pad) * (8 * int(width) + 8)
+# a resident bucket may take this share of an accelerator's memory before
+# the plan streams it: chunk executables and their intermediates need the
+# rest
+_BUCKET_MEMORY_SHARE = 8
+
+
+def default_device_budget() -> Optional[int]:
+    """The per-bucket bytes budget a plan uses when ``max_device_bytes`` is
+    None: none on the CPU, where host memory is the limit, and
+    1/``_BUCKET_MEMORY_SHARE`` of the default device's memory elsewhere."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    return int(limit) // _BUCKET_MEMORY_SHARE if limit else None
 
 
 def _tile_chunk_rows(rows: int, row_bytes: int,
@@ -1140,7 +1169,8 @@ def _plan_intersection(g, variant: str, backend: str, interpret: bool,
                        shape_policy: Optional[ShapePolicy] = None,
                        max_device_bytes: Optional[int] = None,
                        ) -> Tuple[List[_Stage], int, dict]:
-    buckets = _buckets_for_plan(g, variant, widths, prep_backend, shape_policy)
+    buckets = _buckets_for_plan(g, variant, widths, prep_backend,
+                                shape_policy, max_device_bytes)
     # id range covers real vertex ids [0, n) plus the in-row padding
     # sentinels n (u rows) and n+1 (v rows); whole-row padding (-1/-2) is
     # negative and never matches in any core
@@ -1150,23 +1180,28 @@ def _plan_intersection(g, variant: str, backend: str, interpret: bool,
     for b in buckets:
         shape_key = b.shape
         strat, bits = _resolve_bucket_strategy(b.width, id_range, strategy,
-                                               bitmap_bits)
+                                               bitmap_bits, backend)
         e_pad, width = int(shape_key[0]), int(shape_key[1])
         if max_device_bytes is not None \
-                and _bucket_nbytes(e_pad, width) > max_device_bytes:
-            # stream this bucket: host keeps the padded arrays, count()
-            # uploads pow2-row chunks through one chunk-shaped executable
-            chunk = _tile_chunk_rows(e_pad, _bucket_nbytes(1, width),
+                and prep.bucket_nbytes(e_pad, width) > max_device_bytes:
+            # stream this bucket through one chunk-shaped executable:
+            # device prep gathers pow2-row chunks on device at count() time;
+            # host prep keeps the padded arrays and uploads their chunks
+            chunk = _tile_chunk_rows(e_pad, prep.bucket_nbytes(1, width),
                                      max_device_bytes)
             chunk_key = (chunk, width)
             fn = get_executable("intersection", backend, interpret,
                                 chunk_key, strategy=strat, bitmap_bits=bits)
-            vertex_args = None
-            if variant == "filtered":
-                vertex_args = (np.asarray(b.src), np.asarray(b.dst))
+            if isinstance(b, prep.StreamedBucket):
+                host_args, vertex_args, source = (), None, b
+            else:
+                host_args = (np.asarray(b.u_lists), np.asarray(b.v_lists))
+                vertex_args, source = None, None
+                if variant == "filtered":
+                    vertex_args = (np.asarray(b.src), np.asarray(b.dst))
             stages.append(_TiledStage(
                 executable=fn,
-                host_args=(np.asarray(b.u_lists), np.asarray(b.v_lists)),
+                host_args=host_args,
                 fills=(-1, -2),  # whole-row padding: zero matches everywhere
                 chunk_rows=chunk,
                 shape_key=shape_key,
@@ -1174,6 +1209,7 @@ def _plan_intersection(g, variant: str, backend: str, interpret: bool,
                 strategy=strat,
                 bitmap_bits=bits,
                 vertex_args=vertex_args,
+                source=source,
             ))
             tiled_buckets.append(dict(shape=shape_key, chunk_rows=chunk,
                                       num_chunks=stages[-1].num_chunks))
@@ -1544,7 +1580,7 @@ def _plan_bfs(g: Graph, backend: str, interpret: bool,
             bd = np.concatenate([bd, np.zeros(pad, np.int32)])
         shape_key = (e_pad, w)
         strat, bits = _resolve_bucket_strategy(w, id_range, strategy,
-                                               bitmap_bits)
+                                               bitmap_bits, backend)
         fn = get_executable("intersection", backend, interpret, shape_key,
                             strategy=strat, bitmap_bits=bits)
         stages.append(_Stage(
@@ -1599,8 +1635,9 @@ def plan_triangle_count(
         key, one scalar psum per stage).
       backend: "jnp" | "pallas" | "ref" per-kernel execution path.
       interpret: pallas interpret mode (True runs kernel bodies on CPU);
-        None (default) resolves to ``repro.core.options.DEFAULT_INTERPRET``
-        (the ``TC_INTERPRET`` env var, unset ⇒ True).
+        None (default) resolves from the platform the plan runs on —
+        interpreted on the CPU, compiled on a TPU
+        (``repro.core.options.resolve_interpret``).
       variant: intersection lane only — "filtered" (forward algorithm) or
         "full" (every directed edge, each triangle found 6×).
       widths: degree-class bucket widths for the intersection/subgraph lanes.
@@ -1624,8 +1661,9 @@ def plan_triangle_count(
         streamed through one cached chunk-shaped executable at ``count()``
         time (pow2 chunk rows ⇒ monotone shape classes, zero steady-state
         recompiles; counts bit-identical to monolithic). None (default)
-        plans everything resident. Distributed lanes ignore it — the mesh
-        deal already partitions the working set.
+        means ``default_device_budget()``: no budget on the CPU, a share of
+        the device's memory on an accelerator. Distributed lanes ignore it —
+        the mesh deal already partitions the working set.
       mesh: jax device mesh — consumed by the ``*_distributed`` lanes only
         (None there defaults to a 1-D mesh over every visible device,
         matching the historical one-shot functions); single-host lanes
@@ -1637,7 +1675,9 @@ def plan_triangle_count(
       facade (``repro.core.api.TriangleCounter``) and the deprecated
       one-shot ``triangle_count_*`` shims both route here.
     """
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, lane=algorithm, backend=backend)
+    if max_device_bytes is None:
+        max_device_bytes = default_device_budget()
     t0 = time.perf_counter()
     if algorithm == "intersection":
         stages, divisor, meta = _plan_intersection(
@@ -2248,7 +2288,8 @@ class DynamicPlan:
         self.name = g.name
         self.n = int(g.n)
         self.backend = backend
-        self.interpret = resolve_interpret(interpret)
+        self.interpret = resolve_interpret(interpret, lane="dynamic",
+                                           backend=backend)
         self.widths = tuple(int(w) for w in widths)
         self.strategy = strategy
         self.bitmap_bits = bitmap_bits

@@ -9,20 +9,20 @@ cache is keyed by fields derived from these options (see
 imply equal cache keys*: two ``TriangleCounter`` sessions built from equal
 ``CountOptions`` over same-shaped graphs share every compiled executable.
 
-``DEFAULT_INTERPRET`` is the single source of truth for the pallas
-interpret-mode default. It is resolved ONCE at import from the
-``TC_INTERPRET`` environment variable (unset ⇒ ``True``, the CPU-safe
-default; ``TC_INTERPRET=0`` ⇒ ``False`` for real-accelerator runs), replacing
-the per-function ``interpret=True`` defaults that made real-GPU runs pay
-interpreter mode by accident. Every entry point now takes ``interpret=None``
-meaning "use ``DEFAULT_INTERPRET``"; pass an explicit bool to override.
+``resolve_interpret`` is the single source of truth for the Pallas
+interpret mode. Every entry point takes ``interpret=None``, which resolves
+when a plan is built from the platform the plan runs on: the interpreter on
+the CPU, the compiled kernel on a TPU. A Pallas lane never runs interpreted
+on a TPU: asking for it raises ``KernelPlatformError`` naming the lane and
+its kernel, rather than hiding the device behind the interpreter.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Optional, Tuple, Union
+
+import jax
 
 from repro.graphs.device import (
     DEFAULT_SHAPE_POLICY,
@@ -34,8 +34,8 @@ __all__ = [
     "BACKENDS",
     "CHOOSERS",
     "CountOptions",
-    "DEFAULT_INTERPRET",
     "DEFAULT_WIDTHS",
+    "KernelPlatformError",
     "PREP_BACKENDS",
     "VARIANTS",
     "resolve_interpret",
@@ -48,23 +48,52 @@ BACKENDS = ("jnp", "pallas", "ref")
 PREP_BACKENDS = ("device", "host")
 CHOOSERS = ("heuristic", "measured")
 
-_FALSY = ("0", "false", "no", "off", "")
+# the Pallas kernels each lane's backend="pallas" path runs
+_LANE_KERNELS = {
+    "matrix": "masked_spgemm_pallas",
+    "hash": "hash_probe_counts_pallas",
+}
+_INTERSECT_KERNELS = ("intersect_counts_pallas / intersect_counts_probe_pallas"
+                      " / intersect_counts_bitmap_pallas")
 
 
-def _resolve_default_interpret() -> bool:
-    """Read ``TC_INTERPRET`` once; unset means True (CPU-safe)."""
-    raw = os.environ.get("TC_INTERPRET")
-    if raw is None:
-        return True
-    return raw.strip().lower() not in _FALSY
+class KernelPlatformError(RuntimeError):
+    """A lane's Pallas kernel cannot run as asked on the plan's platform."""
+
+    def __init__(self, lane: str, platform: str):
+        self.lane = lane
+        self.kernel = _LANE_KERNELS.get(lane, _INTERSECT_KERNELS)
+        self.platform = platform
+        super().__init__(
+            f"lane {lane!r}: Pallas kernel {self.kernel} would run in the "
+            f"interpreter on {platform!r}; on a TPU the compiled kernel runs "
+            f"(pass interpret=None or False)"
+        )
 
 
-DEFAULT_INTERPRET: bool = _resolve_default_interpret()
+def resolve_interpret(interpret: Optional[bool],
+                      platform: Optional[str] = None, *,
+                      lane: str = "intersection",
+                      backend: str = "pallas") -> bool:
+    """The concrete Pallas interpret flag for a plan on ``platform``.
 
+    Args:
+      interpret: None (default) chooses from the platform — interpret on the
+        CPU, compiled anywhere else; a bool is taken as given.
+      platform: the platform the plan runs on; None means JAX's default
+        backend, read at call time (plan time), never at import.
+      lane / backend: the lane asking and its backend, for the check below.
 
-def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """None ⇒ the process-wide ``DEFAULT_INTERPRET``; else the explicit bool."""
-    return DEFAULT_INTERPRET if interpret is None else bool(interpret)
+    Raises:
+      KernelPlatformError: ``interpret=True`` for a ``backend="pallas"``
+        lane on a TPU.
+    """
+    platform = platform or jax.default_backend()
+    if interpret is None:
+        return platform == "cpu"
+    if interpret and backend == "pallas" and platform == "tpu":
+        raise KernelPlatformError(lane, platform)
+    return bool(interpret)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,8 +117,8 @@ class CountOptions:
       variant: intersection lane — "filtered" (forward algorithm, each
         triangle once) or "full" (every directed edge, found 6×).
       backend: "jnp" | "pallas" | "ref" per-kernel execution path.
-      interpret: pallas interpret mode; None (default) resolves to
-        ``DEFAULT_INTERPRET`` (the ``TC_INTERPRET`` env var).
+      interpret: pallas interpret mode; None (default) resolves at plan
+        time from the platform (``resolve_interpret``).
       strategy: intersection/subgraph lanes — per-bucket set-intersection
         core: "auto" (documented cost model) or forced "broadcast" |
         "probe" | "bitmap".
@@ -136,8 +165,10 @@ class CountOptions:
         int64 keys. See ``repro.graphs.device.resolve_edge_key_mode`` —
         the repo's single capacity checkpoint.
       max_device_bytes: optional per-bucket device-bytes budget for the
-        intersection/subgraph/matrix lanes. ``None`` (default) plans every
-        bucket monolithically; an int budget makes the engine STREAM any
+        intersection/subgraph/matrix lanes. ``None`` (default) means
+        ``engine.default_device_budget()`` — every bucket monolithic on the
+        CPU, a share of the device's memory on an accelerator; an int
+        budget makes the engine STREAM any
         bucket whose device arrays would exceed it through the same cached
         executables chunk-by-chunk (pow2 chunk rows ⇒ monotone chunk shape
         classes, zero steady-state recompiles), accumulating partial counts
@@ -282,8 +313,11 @@ class CountOptions:
 
     @property
     def resolved_interpret(self) -> bool:
-        """The concrete interpret flag (``None`` ⇒ ``DEFAULT_INTERPRET``)."""
-        return resolve_interpret(self.interpret)
+        """The concrete interpret flag on JAX's default backend
+        (``resolve_interpret``)."""
+        lane = "intersection" if self.algorithm == "auto" else self.algorithm
+        return resolve_interpret(self.interpret, lane=lane,
+                                 backend=self.backend)
 
     @property
     def resolved_shape_policy(self) -> ShapePolicy:

@@ -16,7 +16,8 @@ Lanes:
 
 * ``prepare_intersection_buckets_device`` — orientation + bucket layout +
   padded gathers for the intersection lane (and the subgraph lane's join),
-  returning device-resident ``DeviceBucket``s.
+  returning device-resident ``DeviceBucket``s — or, for a bucket past the
+  caller's bytes budget, a ``StreamedBucket`` that gathers chunk by chunk.
 * ``peel_to_two_core_device`` / ``induced_device_graph`` — the subgraph
   lane's FILTER + RECONSTRUCT as device stages (vertex ids are kept, not
   renumbered: dead vertices just lose their rows).
@@ -39,9 +40,11 @@ Lanes:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.graphs.formats import (
@@ -74,6 +77,8 @@ from repro.core.options import DEFAULT_WIDTHS
 
 __all__ = [
     "DeviceBucket",
+    "StreamedBucket",
+    "bucket_nbytes",
     "build_tile_schedule",
     "check_edge_key_range",
     "choose_block",
@@ -118,6 +123,60 @@ class DeviceBucket:
         return (self.e_pad, self.width)
 
 
+def bucket_nbytes(e_pad: int, width: int) -> int:
+    """Device bytes one gathered intersection bucket costs: the (e, w)
+    int32 u/v neighbor-list pair plus the (e,) int32 src/dst endpoints."""
+    return int(e_pad) * (8 * int(width) + 8)
+
+
+@dataclasses.dataclass
+class StreamedBucket:
+    """A degree-class bucket too large to gather whole.
+
+    Its sorted edge endpoints and the shared padded neighbor table stay on
+    device; ``gather(offset, rows)`` materializes rows ``[offset, offset +
+    rows)`` of the ``DeviceBucket`` layout it stands for — (u, v, src, dst),
+    with rows past ``edges`` as whole-row padding. One compiled gather
+    serves every chunk of every same-width bucket of a ``rows`` class.
+    """
+
+    width: int
+    edges: int
+    e_pad: int
+    n: int
+    sorted_src: jnp.ndarray
+    sorted_dst: jnp.ndarray
+    start: int
+    nbrs: jnp.ndarray
+
+    @property
+    def shape(self) -> tuple:
+        return (self.e_pad, self.width)
+
+    def _window(self, offset: int, rows: int) -> tuple:
+        return (self.sorted_src, self.sorted_dst,
+                jnp.int32(self.start + offset),
+                jnp.int32(max(0, min(rows, self.edges - offset))), self.nbrs)
+
+    def gather(self, offset: int, rows: int):
+        return _gather_bucket_dev(*self._window(offset, rows), n=self.n,
+                                  e_pad=int(rows), width=self.width)
+
+    def count(self, fn, offset: int, rows: int):
+        """``fn(u, v)`` over the chunk ``gather(offset, rows)`` would
+        return, gathered and counted in one dispatch."""
+        return _gathered_count(fn, *self._window(offset, rows), n=self.n,
+                               rows=int(rows), width=self.width)
+
+
+@functools.partial(jax.jit, static_argnames=("fn", "n", "rows", "width"))
+def _gathered_count(fn, sorted_src, sorted_dst, start, count, nbrs, *,
+                    n: int, rows: int, width: int):
+    u, v, _, _ = _gather_bucket_dev(sorted_src, sorted_dst, start, count,
+                                    nbrs, n=n, e_pad=rows, width=width)
+    return fn(u, v)
+
+
 def _as_device_graph(g: Union[Graph, DeviceGraph],
                      policy: Optional[ShapePolicy]) -> DeviceGraph:
     if isinstance(g, DeviceGraph):
@@ -131,7 +190,8 @@ def prepare_intersection_buckets_device(
     variant: str = "filtered",
     widths: Sequence[int] = DEFAULT_WIDTHS,
     policy: Optional[ShapePolicy] = None,
-) -> List[DeviceBucket]:
+    max_bucket_bytes: Optional[int] = None,
+) -> List[Union[DeviceBucket, StreamedBucket]]:
     """Device-resident intersection prep: orientation + bucket layout +
     padded neighbor gathers, all jitted.
 
@@ -143,9 +203,13 @@ def prepare_intersection_buckets_device(
         final next-pow2 bucket, exactly as the host path.
       policy: the ``ShapePolicy`` rounding per-bucket extents (ignored when
         ``g`` is already a ``DeviceGraph``, which carries its own).
+      max_bucket_bytes: a bucket whose gathered arrays
+        (``bucket_nbytes``) would exceed this is returned as a
+        ``StreamedBucket`` instead of being gathered; None gathers all.
 
     Returns:
-      A list of ``DeviceBucket``; empty degree classes are dropped. Host
+      A list of ``DeviceBucket`` / ``StreamedBucket``; empty degree classes
+      are dropped. Host
       syncs: one small transfer for the per-bucket counts and max degree —
       everything else stays on device.
     """
@@ -184,6 +248,13 @@ def prepare_intersection_buckets_device(
         if c == 0:
             continue
         e_pad = dg.policy.round_edges(c)
+        if max_bucket_bytes is not None \
+                and bucket_nbytes(e_pad, w) > max_bucket_bytes:
+            out.append(StreamedBucket(
+                width=w, edges=c, e_pad=e_pad, n=n, sorted_src=ssrc,
+                sorted_dst=sdst, start=int(counts_h[:i].sum()),
+                nbrs=nbrs))
+            continue
         u, v, sb, db = _gather_bucket_dev(
             ssrc, sdst, starts[i], counts[i], nbrs,
             n=n, e_pad=e_pad, width=w,

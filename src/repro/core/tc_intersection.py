@@ -63,7 +63,7 @@ def triangle_count_intersection(
 
     Use ``TriangleCounter(g, CountOptions(algorithm="intersection", ...))``
     instead. Keyword arguments map 1:1 onto ``CountOptions`` fields
-    (``interpret=None`` now means the process-wide ``DEFAULT_INTERPRET``).
+    (``interpret=None`` resolves from the platform, ``resolve_interpret``).
 
     Returns:
       The exact triangle count as a Python int (unchanged behavior).

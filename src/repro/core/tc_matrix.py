@@ -66,8 +66,8 @@ def triangle_count_matrix(
     """Deprecated shim: exact triangle count via fused masked block-SpGEMM.
 
     Use ``TriangleCounter(g, CountOptions(algorithm="matrix", ...))``
-    instead; ``interpret=None`` now means the process-wide
-    ``DEFAULT_INTERPRET``. Returns the exact count as a Python int
+    instead; ``interpret=None`` resolves from the platform
+    (``resolve_interpret``). Returns the exact count as a Python int
     (unchanged behavior).
     """
     from repro.core.api import TriangleCounter, warn_deprecated

@@ -71,8 +71,8 @@ def triangle_count_subgraph(
 
     Use ``TriangleCounter(g, CountOptions(algorithm="subgraph", ...))``
     instead — ``CountResult.meta`` carries the stats ``return_stats=True``
-    returns here. ``interpret=None`` now means the process-wide
-    ``DEFAULT_INTERPRET``. Return values are unchanged: an int, or
+    returns here. ``interpret=None`` resolves from the platform
+    (``resolve_interpret``). Return values are unchanged: an int, or
     ``(int, stats dict)`` with ``return_stats=True``.
     """
     from repro.core.api import TriangleCounter, warn_deprecated
@@ -110,12 +110,12 @@ def subgraph_match_triangle(
     paper highlights for the SM formulation: 'find the embeddings of triangles
     with certain label patterns').
 
-    ``interpret=None`` resolves to the process-wide ``DEFAULT_INTERPRET``.
+    ``interpret=None`` resolves from the platform (``resolve_interpret``).
 
     Returns the number of ordered embeddings (u,v,w) with labels matching
     (q0,q1,q2) and {u,v},{v,w},{u,w} ∈ E.
     """
-    interpret = resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret, lane="subgraph", backend=backend)
     labels = np.asarray(labels)
     q0, q1, q2 = query_labels
     # candidate vertices: label in query labels, degree ≥ 2, 2-core
@@ -154,7 +154,7 @@ def subgraph_match_triangle(
         v_lists[v_lists == sub.n] = sub.n + 1
         # same per-bucket dispatch as the unlabeled lanes (id range covers
         # real ids plus the n / n+1 sentinels)
-        strat, bits = resolve_strategy(b["width"], sub.n + 2)
+        strat, bits = resolve_strategy(b["width"], sub.n + 2, backend=backend)
         run = get_executable(
             "intersection", backend, interpret, tuple(u_lists.shape),
             strategy=strat, bitmap_bits=bits,

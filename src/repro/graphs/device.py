@@ -44,7 +44,6 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64 as _enable_x64
 
 from repro.graphs.formats import Graph
 
@@ -176,7 +175,7 @@ def edge_key_context(mode: str):
     ``enable_x64`` scope — even on arrays created inside one — so BOTH the
     trace and every call of a wide-key executable must be wrapped. The
     int32 fast path gets a no-op context, keeping call sites uniform."""
-    return _enable_x64() if mode == "wide" else contextlib.nullcontext()
+    return jax.enable_x64(True) if mode == "wide" else contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)

@@ -38,10 +38,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, out_ref, *, block_k: int, seq_k: int,
 
     def body(kb, carry):
         m_run, l_run, acc = carry
-        k_tile = pl.load(k_ref, (pl.dslice(kb * block_k, block_k),
-                                 slice(None)))  # (BK, hd)
-        v_tile = pl.load(v_ref, (pl.dslice(kb * block_k, block_k),
-                                 slice(None)))
+        k_tile = k_ref[pl.ds(kb * block_k, block_k), :]  # (BK, hd)
+        v_tile = v_ref[pl.ds(kb * block_k, block_k), :]
         # logits: (BQ*G, BK) via 2-D matmul on the MXU
         qf = q.astype(jnp.float32).reshape(block_q * g, hd)
         logits = jax.lax.dot_general(

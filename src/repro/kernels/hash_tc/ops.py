@@ -9,7 +9,7 @@ hash table* — O(D) slot compares per probe instead of O(W) or O(log W):
     backend      core                                  notes
     --------     ----------------------------------   -------------------------
     "jnp"        ``hash_probe_counts_jnp``             chunked gather, CPU path
-    "pallas"     ``hash_probe_counts_pallas``          table-in-VMEM TPU kernel
+    "pallas"     ``hash_probe_counts_pallas``          table-in-HBM TPU kernel
     "ref"        ``hash_probe_counts_ref``             structure-blind oracle
 
 Sentinel rules (shared with the rest of the repo): candidate rows are the
@@ -65,7 +65,7 @@ def hash_probe_counts(
       src: (E,) int32 anchor vertex per row (padding rows carry 0).
       table: (n, B, D) int32 per-vertex hash table from
         ``build_hash_table``; B must be a power of two.
-      backend: "pallas" (table-in-VMEM TPU kernel), "jnp" (chunked-gather
+      backend: "pallas" (table-in-HBM TPU kernel), "jnp" (chunked-gather
         production path), or "ref" (the structure-blind oracle).
       tile_edges: pallas grid tile height; E is sentinel-row-padded to a
         multiple of it and the padding stripped from the result.

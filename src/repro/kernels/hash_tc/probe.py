@@ -13,19 +13,21 @@ Two implementations of the same semantics:
                                  all probes with one ``take_along_axis``;
                                  ``lax.map``-chunked so the (C, B, D) gather
                                  stays inside a fixed element budget.
-* ``hash_probe_counts_pallas`` — a Pallas kernel: the whole flattened table
-                                 sits in VMEM, each grid step loads a
-                                 (TE, W) probe tile and walks its rows with
-                                 ``pl.ds`` dynamic slices + an in-register
-                                 bucket gather.
+* ``hash_probe_counts_pallas`` — a Pallas kernel: the table stays in HBM;
+                                 each grid step loads a (TE, B) probe tile
+                                 and the tile's anchors into SMEM, then per
+                                 edge copies the anchor's (D, B) table row
+                                 into VMEM and resolves its probes with one
+                                 128-lane-slab bucket gather.
 
 Probe-validity rule: only values in [0, n) probe; the candidate rows' in-row
 sentinel (n + 1) and whole-row padding (-2) are masked out, and empty table
 slots hold -1 which no valid probe can equal.
 
-VMEM budget (pallas): the table is not tiled — n·B·D·4B must fit beside the
-(TE, W) probe tile; with n=8192, B=64, D=4 that is ~8 MB. Wider tables want
-the jnp path (documented in docs/ARCHITECTURE.md).
+VMEM budget (pallas): 2 · 2 · TE·B·4B double-buffered probe tiles + one
+(max(D, 2), max(B, 128)) table row + a few (D, B) intermediates; TE=128,
+B=512 is ~1.1 MB. The table itself is only bounded by HBM. The per-edge row
+copy is serial (start, then wait before the gather).
 """
 
 from __future__ import annotations
@@ -35,6 +37,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.lanes import LANE, lane_gather
 
 __all__ = ["hash_probe_counts_jnp", "hash_probe_counts_pallas"]
 
@@ -88,21 +93,25 @@ def hash_probe_counts_jnp(
     return out.reshape(-1)[:e]
 
 
-def _hash_probe_kernel(w_ref, src_ref, tbl_ref, out_ref, *, num_buckets: int, n: int):
-    w = w_ref[...]  # (TE, W) int32 candidate rows
+def _hash_probe_kernel(src_ref, w_ref, tbl_hbm, out_ref, row_ref, sem, *,
+                       num_buckets: int, n: int):
+    te = w_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, te), 1)
 
-    def body(i, carry):
-        u = src_ref[i, 0]
-        tbl = tbl_ref[pl.ds(u * num_buckets, num_buckets), :]  # (B, D)
-        row = w[i, :]
+    def body(i, acc):
+        copy = pltpu.make_async_copy(tbl_hbm.at[src_ref[0, i]], row_ref, sem)
+        copy.start()
+        row = w_ref[pl.ds(i, 1), :]  # (1, B) candidates of edge i
         valid = (row >= 0) & (row < n)
         bkt = jnp.where(valid, row & (num_buckets - 1), 0)
-        cand = jnp.take(tbl, bkt, axis=0)  # (W, D) in-register gather
-        hit = jnp.any(cand == row[:, None], axis=-1) & valid
-        pl.store(out_ref, (pl.ds(i, 1),), hit.sum(dtype=jnp.int32)[None])
-        return carry
+        copy.wait()
+        tbl = row_ref[...]  # (D, B): chain slot d of every bucket
+        cand = lane_gather(tbl, jnp.concatenate([bkt] * tbl.shape[0], axis=0))
+        hit = jnp.max((cand == row).astype(jnp.int32), axis=0, keepdims=True)
+        cnt = jnp.sum(hit * valid.astype(jnp.int32), axis=1, keepdims=True)
+        return jnp.where(lane == i, cnt, acc)
 
-    jax.lax.fori_loop(0, w.shape[0], body, 0)
+    out_ref[...] = jax.lax.fori_loop(0, te, body, jnp.zeros((1, te), jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_edges", "interpret"))
@@ -120,9 +129,10 @@ def hash_probe_counts_pallas(
       w_lists: (E, W) int32 candidate rows; E must be a multiple of
         ``tile_edges`` (ops.py pads with sentinel rows).
       src: (E,) int32 anchor vertices (padding rows carry 0).
-      table: (n, B, D) int32 hash table, B a power of two; resident in VMEM
-        un-tiled (see module docstring for the budget).
-      tile_edges: probe rows per grid step.
+      table: (n, B, D) int32 hash table, B a power of two and ≥ W; it stays
+        in HBM and each edge copies its anchor's (D, B) row into VMEM.
+      tile_edges: probe rows per grid step; a multiple of 128 (the lane
+        width of the (1, TE) output block).
       interpret: run the kernel body on CPU for validation; pass False on a
         real TPU.
 
@@ -131,18 +141,29 @@ def hash_probe_counts_pallas(
     """
     e, w = w_lists.shape
     n, num_buckets, depth = table.shape
-    assert e % tile_edges == 0, (e, tile_edges)
-    flat = table.reshape(n * num_buckets, depth)
-    grid = (e // tile_edges,)
-    return pl.pallas_call(
+    assert e % tile_edges == 0 and w <= num_buckets, (e, tile_edges, w)
+    # the kernel's layout: (n, D, B) rows of at least 2 chain slots × 128
+    # lanes (Mosaic's DMA and lane-gather minimums), padded with empty
+    # slots; candidate rows widened to the same lanes with never-probing ids
+    lanes, slots = max(num_buckets, LANE), max(depth, 2)
+    tbl = jnp.pad(table.transpose(0, 2, 1),
+                  ((0, 0), (0, slots - depth), (0, lanes - num_buckets)),
+                  constant_values=-1)
+    w_lists = jnp.pad(w_lists, ((0, 0), (0, lanes - w)), constant_values=-2)
+    groups = e // tile_edges
+    out = pl.pallas_call(
         functools.partial(_hash_probe_kernel, num_buckets=num_buckets, n=n),
-        grid=grid,
+        grid=(groups,),
         in_specs=[
-            pl.BlockSpec((tile_edges, w), lambda i: (i, 0)),
-            pl.BlockSpec((tile_edges, 1), lambda i: (i, 0)),
-            pl.BlockSpec((n * num_buckets, depth), lambda i: (0, 0)),
+            pl.BlockSpec((None, 1, tile_edges), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((tile_edges, lanes), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((tile_edges,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
+        out_specs=pl.BlockSpec((1, tile_edges), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, e), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((slots, lanes), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(w_lists, src.reshape(e, 1), flat)
+    )(src.reshape(groups, 1, tile_edges), w_lists, tbl)
+    return out.reshape(e)

@@ -20,8 +20,9 @@ intersection is computed) times three *backends* (where it runs):
 
 ``choose_strategy`` is the documented cost model behind ``strategy="auto"``:
 bitmap when the id range fits the packed width (a ~32× compare reduction),
-probe for wide buckets (W ≥ 64, past the measured O(W²)/O(W log W)
-crossover), broadcast for narrow ones. ``resolve_strategy`` additionally
+probe for wide buckets (W ≥ 64, past the O(W²)/O(W log W) crossover measured
+on the CPU), broadcast for narrow ones — and broadcast for the ``jnp`` core
+on a TPU, where the vmapped ``searchsorted`` probe is gather-bound. ``resolve_strategy`` additionally
 picks the bitmap capacity; the engine applies it per degree bucket and bakes
 the result into the executable-cache key.
 
@@ -100,7 +101,8 @@ def packed_bits(width: int) -> int:
     return _ceil32(width)
 
 
-def choose_strategy(width: int, id_range=None) -> str:
+def choose_strategy(width: int, id_range=None, backend: str = "jnp",
+                    platform=None) -> str:
     """The ``strategy="auto"`` cost model. Pure function, documented contract.
 
     Args:
@@ -108,28 +110,39 @@ def choose_strategy(width: int, id_range=None) -> str:
       id_range: number of distinct ids the lists may contain (the engine
         passes ``n + 2`` to cover the in-row sentinels ``n`` and ``n + 1``);
         None when unknown (e.g. under tracing), which disqualifies bitmap.
+      backend: the backend that will run the core ("jnp" | "pallas").
+      platform: where it runs; None means JAX's default backend.
 
     Returns:
       "bitmap" when ``id_range`` fits the packed width (membership tests
       collapse to shift/AND over W/32 words; the packed width must also stay
       under ``BITMAP_MAX_BITS``), else "probe" for wide buckets (W ≥ 64),
-      else "broadcast" for narrow ones.
+      else "broadcast" for narrow ones. The one exception is the ``jnp``
+      probe core on a TPU: its vmapped ``searchsorted`` is gather-bound
+      there (on a v5e, the ``strategy`` phase of ``chip_smoke.py``, which
+      measures this on every run, read it 38–40× slower than the ``jnp``
+      broadcast core at W = 128 and 116–117× at W = 512 over 4,096 rows,
+      two runs), so wide buckets take broadcast.
     """
     pw = packed_bits(width)
     if id_range is not None and int(id_range) <= pw and pw <= BITMAP_MAX_BITS:
         return "bitmap"
+    if backend == "jnp" and (platform or jax.default_backend()) == "tpu":
+        return "broadcast"
     if width >= _PROBE_MIN_WIDTH:
         return "probe"
     return "broadcast"
 
 
-def resolve_strategy(width: int, id_range=None, strategy: str = "auto"):
+def resolve_strategy(width: int, id_range=None, strategy: str = "auto",
+                     backend: str = "jnp"):
     """Resolve ("auto" or explicit) strategy to (strategy, bitmap_bits).
 
     ``bitmap_bits`` is None except for the bitmap strategy, where it is
     ``packed_bits(width)`` when the id range fits (so same-shaped buckets from
     different graphs share one executable-cache entry) and the id range
     rounded up to a word multiple when bitmap is forced beyond it.
+    ``backend`` is the backend that will run the core (``choose_strategy``).
 
     Raises:
       ValueError: strategy="bitmap" forced with no ``id_range`` to size the
@@ -137,7 +150,7 @@ def resolve_strategy(width: int, id_range=None, strategy: str = "auto"):
         packed bits, or an unknown strategy name.
     """
     if strategy == "auto":
-        strategy = choose_strategy(width, id_range)
+        strategy = choose_strategy(width, id_range, backend)
     if strategy not in STRATEGIES:
         raise ValueError(
             f"unknown strategy {strategy!r}; expected 'auto' or one of {STRATEGIES}"
@@ -417,7 +430,8 @@ def intersect_counts(
         # width-only probe/broadcast rule applies, so auto never selects a
         # bitmap whose capacity the data wasn't checked against
         strategy, bits = resolve_strategy(
-            u_lists.shape[1], _auto_id_range(u_lists, v_lists)
+            u_lists.shape[1], _auto_id_range(u_lists, v_lists),
+            backend=backend,
         )
         if strategy == "bitmap":
             bitmap_bits = bits

@@ -14,15 +14,19 @@ Two implementations of the same semantics:
                                       fixed-iteration lower-bound search per
                                       lane: every u element in a (TE, W) tile
                                       searches its v row in ``bit_length(W)``
-                                      compare/select rounds, each a gather +
-                                      VPU select at full vector width.
+                                      compare/select rounds, each a lane
+                                      gather (128-lane slabs, see
+                                      ``repro.kernels.lanes``) + VPU select
+                                      at full vector width.
 
 Both require rows sorted ascending. Padding follows the repo-wide sentinel
 rule: u rows pad with one value, v rows with a *different* value, so padding
 never probes successfully.
 
-VMEM budget (pallas): 2 · TE·W·4B inputs + 4 · TE·W·4B search state; with
-TE=256, W=512 that is ~3.1 MB — under the ~16 MB/core budget.
+VMEM budget (pallas): 2 · 2 · TE·W·4B double-buffered inputs + about
+6 · TE·W·4B search state (lo, hi, mid, the gathered v values, the slab
+select and the found mask) + 2 · TE·4B out; with TE=256, W=512 that is
+~5 MB — under the 16 MB scoped VMEM default of a v5e core.
 """
 
 from __future__ import annotations
@@ -32,6 +36,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.lanes import lane_gather, row_counts
 
 __all__ = ["intersect_counts_probe", "intersect_counts_probe_pallas"]
 
@@ -69,13 +75,13 @@ def _probe_kernel(u_ref, v_ref, out_ref, *, width: int):
     for _ in range(max(1, int(width).bit_length())):
         active = lo < hi
         mid = (lo + hi) // 2  # active lanes have mid ∈ [lo, hi) ⊂ [0, W)
-        v_mid = jnp.take_along_axis(v, jnp.clip(mid, 0, width - 1), axis=1)
+        v_mid = lane_gather(v, jnp.clip(mid, 0, width - 1))
         go_right = active & (v_mid < u)
         lo = jnp.where(go_right, mid + 1, lo)
         hi = jnp.where(active & ~go_right, mid, hi)
     pos = jnp.clip(lo, 0, width - 1)
-    found = (jnp.take_along_axis(v, pos, axis=1) == u) & (lo < width)
-    out_ref[...] = found.sum(axis=1).astype(jnp.int32)
+    found = (lane_gather(v, pos) == u) & (lo < width)
+    out_ref[...] = row_counts(found.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_edges", "interpret"))
@@ -92,7 +98,8 @@ def intersect_counts_probe_pallas(
       u_lists: (E, W) int32 sorted rows; E must be a multiple of
         ``tile_edges`` (callers pad with sentinel rows — see ops.py).
       v_lists: (E, W) int32 sorted rows, disjoint padding sentinel.
-      tile_edges: rows per grid step (VMEM tile height).
+      tile_edges: rows per grid step; a multiple of 128 (the lane width of
+        the (1, TE) output block).
       interpret: run the kernel body on CPU for validation; pass False on a
         real TPU.
 
@@ -101,15 +108,15 @@ def intersect_counts_probe_pallas(
     """
     e, w = u_lists.shape
     assert e % tile_edges == 0, (e, tile_edges)
-    grid = (e // tile_edges,)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_probe_kernel, width=w),
-        grid=grid,
+        grid=(e // tile_edges,),
         in_specs=[
             pl.BlockSpec((tile_edges, w), lambda i: (i, 0)),
             pl.BlockSpec((tile_edges, w), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_edges,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
+        out_specs=pl.BlockSpec((1, tile_edges), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, e), jnp.int32),
         interpret=interpret,
     )(u_lists, v_lists)
+    return out.reshape(e)

@@ -42,7 +42,8 @@ def _masked_spgemm_kernel(l_ref, u_ref, a_ref, out_ref):
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),  # batched (B,B)@(B,B)
         preferred_element_type=jnp.float32,
     )
-    out_ref[...] = (prod * a).sum(axis=(1, 2))
+    rows = (prod * a).sum(axis=2)  # (TT, B)
+    out_ref[...] = jnp.sum(rows.T, axis=0, keepdims=True)  # (1, TT)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_triples", "interpret"))
@@ -71,16 +72,19 @@ def masked_spgemm_pallas(
     """
     t, b, b2 = l_tiles.shape
     assert b == b2 and t % tile_triples == 0, (t, b, b2, tile_triples)
-    grid = (t // tile_triples,)
-    return pl.pallas_call(
+    groups = t // tile_triples
+    # one (1, TT) row per grid step: a block whose last two dims equal the
+    # array's, so any TT satisfies the (8, 128) tiling rule
+    out = pl.pallas_call(
         _masked_spgemm_kernel,
-        grid=grid,
+        grid=(groups,),
         in_specs=[
             pl.BlockSpec((tile_triples, b, b), lambda i: (i, 0, 0)),
             pl.BlockSpec((tile_triples, b, b), lambda i: (i, 0, 0)),
             pl.BlockSpec((tile_triples, b, b), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_triples,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, tile_triples), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((groups, 1, tile_triples), jnp.float32),
         interpret=interpret,
     )(l_tiles, u_tiles, a_tiles)
+    return out.reshape(t)

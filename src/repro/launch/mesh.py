@@ -25,15 +25,11 @@ __all__ = ["make_mesh", "make_production_mesh", "make_local_mesh", "mesh_axes"]
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types (forward-compatible);
-    older jax has no AxisType and defaults every axis to Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """jax.make_mesh with explicit Auto axis types."""
     return jax.make_mesh(
         tuple(shape),
         tuple(axes),
-        axis_types=(axis_type.Auto,) * len(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 

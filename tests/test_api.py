@@ -63,10 +63,10 @@ def test_count_options_hash_stability():
     assert hash(o1) == hash(o2)
     assert o1.key() == o2.key()
     assert o1.widths == (8, 32)  # normalized to a tuple
-    # interpret=None resolves to DEFAULT_INTERPRET inside key()
-    from repro.core import DEFAULT_INTERPRET
+    # interpret=None resolves from the platform inside key()
+    from repro.core import resolve_interpret
     assert CountOptions(interpret=None).key() == \
-        CountOptions(interpret=DEFAULT_INTERPRET).key()
+        CountOptions(interpret=resolve_interpret(None)).key()
     # replace() re-validates
     assert o1.replace(strategy="probe").strategy == "probe"
     with pytest.raises(ValueError):
@@ -397,19 +397,18 @@ def test_load_dataset_unknown_name_lists_available():
 
 # --- interpret default satellite --------------------------------------------
 
-def test_default_interpret_env_override():
-    import subprocess, sys, os
-    code = ("import repro.core.options as o; "
-            "print(o.DEFAULT_INTERPRET, o.resolve_interpret(None), "
-            "o.resolve_interpret(True))")
-    env = dict(os.environ, PYTHONPATH="src", TC_INTERPRET="0")
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, cwd=os.path.dirname(
-                             os.path.dirname(os.path.abspath(__file__))))
-    assert out.returncode == 0, out.stderr
-    assert out.stdout.split() == ["False", "False", "True"]
-    env["TC_INTERPRET"] = "1"
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, cwd=os.path.dirname(
-                             os.path.dirname(os.path.abspath(__file__))))
-    assert out.stdout.split() == ["True", "True", "True"]
+def test_interpret_none_resolves_from_platform():
+    from repro.core import KernelPlatformError, resolve_interpret
+    # None: the interpreter on the CPU, the compiled kernel on a TPU
+    assert resolve_interpret(None, "cpu") is True
+    assert resolve_interpret(None, "tpu") is False
+    # this suite runs on the CPU backend, so the default platform is "cpu"
+    assert resolve_interpret(None) is True
+    # explicit flags are kept, except interpreting a Pallas lane on a TPU
+    assert resolve_interpret(False, "cpu") is False
+    assert resolve_interpret(True, "tpu", backend="jnp") is True
+    with pytest.raises(KernelPlatformError) as err:
+        resolve_interpret(True, "tpu", lane="hash")
+    assert err.value.lane == "hash"
+    assert err.value.kernel == "hash_probe_counts_pallas"
+    assert "hash_probe_counts_pallas" in str(err.value)

@@ -164,10 +164,7 @@ out["param_parity"] = all(
 # compressed psum on a real mesh axis
 from repro.train.compression import ef_psum, ef_init
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax ships it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def worker(g):
     deq, _ = ef_psum({"w": g}, ef_init({"w": g}), "data")

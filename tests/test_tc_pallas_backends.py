@@ -182,3 +182,16 @@ def test_distributed_intersection_strategies():
     assert line, proc.stdout
     out = json.loads(line[0][len("RESULT:"):])
     assert all(out.values()), out
+
+
+@pytest.mark.parametrize("width,id_range,backend,platform,want", [
+    (512, None, "jnp", "cpu", "probe"),
+    (512, None, "pallas", "tpu", "probe"),
+    (512, None, "jnp", "tpu", "broadcast"),  # gather-bound jnp probe
+    (128, 10**6, "jnp", "tpu", "broadcast"),
+    (32, 30, "jnp", "tpu", "bitmap"),
+    (32, None, "pallas", "tpu", "broadcast"),
+])
+def test_auto_strategy_follows_backend_and_platform(width, id_range, backend,
+                                                    platform, want):
+    assert choose_strategy(width, id_range, backend, platform) == want
