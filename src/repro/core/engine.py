@@ -110,7 +110,7 @@ from repro.graphs.device import (
     resolve_edge_key_mode,
     shard_valid_counts,
 )
-from repro.core import prep
+from repro.core import prep, tracing
 # _two_core_peel: back-compat re-export (it lived here before PR 4)
 from repro.core.prep import DeviceBucket, _two_core_peel  # noqa: F401
 from repro.core.options import DEFAULT_WIDTHS, resolve_interpret
@@ -325,8 +325,7 @@ _EXECUTABLE_CACHE = _BoundedLRU(_env_cache_size())
 
 
 def _build_intersect_executable(strategy: str, backend: str, interpret: bool,
-                                bitmap_bits) -> Callable:
-    @jax.jit
+                                bitmap_bits, name: str = "run") -> Callable:
     def run(u_lists, v_lists):
         counts = intersect_counts(
             u_lists, v_lists, strategy=strategy, backend=backend,
@@ -334,21 +333,22 @@ def _build_intersect_executable(strategy: str, backend: str, interpret: bool,
         )
         return jnp.sum(counts)
 
-    return run
+    return tracing.jit(name, run)
 
 
-def _build_matrix_executable(backend: str, interpret: bool) -> Callable:
-    @jax.jit
+def _build_matrix_executable(backend: str, interpret: bool,
+                             name: str = "run") -> Callable:
     def run(l_tiles, u_tiles, a_tiles):
         partials = masked_spgemm_counts(
             l_tiles, u_tiles, a_tiles, backend=backend, interpret=interpret
         )
         return jnp.sum(partials)
 
-    return run
+    return tracing.jit(name, run)
 
 
-def _build_hash_executable(backend: str, interpret: bool) -> Callable:
+def _build_hash_executable(backend: str, interpret: bool,
+                           name: str = "run") -> Callable:
     """Per-bucket total for the TRUST-style hashing lane.
 
     The stage args are ``(v_lists, src, table)``: the bucket's candidate
@@ -361,14 +361,13 @@ def _build_hash_executable(backend: str, interpret: bool) -> Callable:
     depend on it.
     """
 
-    @jax.jit
     def run(w_lists, src, table):
         counts = hash_probe_counts(
             w_lists, src, table, backend=backend, interpret=interpret
         )
         return jnp.sum(counts)
 
-    return run
+    return tracing.jit(name, run)
 
 
 def _build_vertex_executable(n: int) -> Callable:
@@ -588,7 +587,8 @@ def _build_delta_executable(strategy: str, bitmap_bits: Optional[int],
 
 def _build_dist_intersect_executable(strategy: str,
                                      bitmap_bits: Optional[int],
-                                     shape_key: tuple, mesh) -> Callable:
+                                     shape_key: tuple, mesh,
+                                     name: str = "run") -> Callable:
     """One degree bucket's sharded intersection count: every shard runs the
     resolved jnp core over its dealt rows, length-gated so padding costs
     nothing, and ONE scalar psum yields the global partial.
@@ -604,7 +604,6 @@ def _build_dist_intersect_executable(strategy: str,
     axes = tuple(mesh.axis_names)
     spec = PartitionSpec(axes)
 
-    @jax.jit
     def run(u, v, valid):
         def local(u, v, valid):
             u, v, valid = u[0], v[0], valid[0]
@@ -628,10 +627,11 @@ def _build_dist_intersect_executable(strategy: str,
                          out_specs=PartitionSpec(),
                          check_vma=False)(u, v, valid)
 
-    return run
+    return tracing.jit(name, run)
 
 
-def _build_dist_matrix_executable(shape_key: tuple, mesh) -> Callable:
+def _build_dist_matrix_executable(shape_key: tuple, mesh,
+                                  name: str = "run") -> Callable:
     """The sharded masked block-SpGEMM count: each shard reduces its dealt
     tile triples locally, one scalar psum yields the global sum.
 
@@ -643,7 +643,6 @@ def _build_dist_matrix_executable(shape_key: tuple, mesh) -> Callable:
     axes = tuple(mesh.axis_names)
     spec = PartitionSpec(axes)
 
-    @jax.jit
     def run(l, u, a, valid):
         def local(l, u, a, valid):
             l, u, a, valid = l[0], u[0], a[0], valid[0]
@@ -663,7 +662,7 @@ def _build_dist_matrix_executable(shape_key: tuple, mesh) -> Callable:
                          out_specs=PartitionSpec(),
                          check_vma=False)(l, u, a, valid)
 
-    return run
+    return tracing.jit(name, run)
 
 
 def _build_dist_edge_executable(strategy: str, bitmap_bits: Optional[int],
@@ -691,6 +690,27 @@ def _build_dist_edge_executable(strategy: str, bitmap_bits: Optional[int],
         )(u_lists, v_lists, src, dst, row_ptr)
 
     return run
+
+
+# a count lane's executable is named by the lane, its strategy and the
+# bucket width ("w") or tile block ("b") in its shape key's second place
+_COUNT_LANES = {"intersection": ("intersect", "w"),
+                "subgraph": ("intersect", "w"),
+                "hash": ("hash", "w"),
+                "matrix": ("matrix", "b"),
+                "intersection_distributed": ("intersect_dist", "w"),
+                "matrix_distributed": ("matrix_dist", "b")}
+
+
+def _executable_name(algorithm: str, strategy: Optional[str],
+                     shape_key: tuple) -> Optional[str]:
+    """The name a device profile shows for a count lane's executable, e.g.
+    ``tc_intersect_broadcast_w512``; None for the other lanes."""
+    if algorithm not in _COUNT_LANES:
+        return None
+    lane, axis = _COUNT_LANES[algorithm]
+    strat = f"_{strategy}" if strategy else ""
+    return f"tc_{lane}{strat}_{axis}{int(shape_key[1])}"
 
 
 def get_executable(algorithm: str, backend: str, interpret: bool,
@@ -755,13 +775,14 @@ def get_executable(algorithm: str, backend: str, interpret: bool,
     if algorithm.endswith("_distributed") and mesh is None:
         raise ValueError(
             f"algorithm {algorithm!r} needs a mesh; pass mesh=")
+    name = _executable_name(algorithm, strategy, shape_key)
     builders: Dict[str, Callable[[], Callable]] = {
         "intersection": lambda: _build_intersect_executable(
-            strategy, backend, interpret, bitmap_bits),
+            strategy, backend, interpret, bitmap_bits, name),
         "subgraph": lambda: _build_intersect_executable(
-            strategy, backend, interpret, bitmap_bits),
-        "matrix": lambda: _build_matrix_executable(backend, interpret),
-        "hash": lambda: _build_hash_executable(backend, interpret),
+            strategy, backend, interpret, bitmap_bits, name),
+        "matrix": lambda: _build_matrix_executable(backend, interpret, name),
+        "hash": lambda: _build_hash_executable(backend, interpret, name),
         "vertex": lambda: _build_vertex_executable(int(shape_key[-1])),
         "edge": lambda: _build_edge_executable(
             strategy, bitmap_bits, tuple(shape_key)),
@@ -770,9 +791,9 @@ def get_executable(algorithm: str, backend: str, interpret: bool,
         "delta": lambda: _build_delta_executable(
             strategy, bitmap_bits, tuple(shape_key)),
         "intersection_distributed": lambda: _build_dist_intersect_executable(
-            strategy, bitmap_bits, tuple(shape_key), mesh),
+            strategy, bitmap_bits, tuple(shape_key), mesh, name),
         "matrix_distributed": lambda: _build_dist_matrix_executable(
-            tuple(shape_key), mesh),
+            tuple(shape_key), mesh, name),
         "edge_distributed": lambda: _build_dist_edge_executable(
             strategy, bitmap_bits, tuple(shape_key), mesh),
     }
@@ -797,7 +818,6 @@ def _build_batch_executable(specs: tuple, backend: str,
     single traced computation: ONE device dispatch per batch.
     """
 
-    @jax.jit
     def run(*arrays):
         total = jnp.zeros(arrays[0].shape[0], jnp.int32)
         for i, (strat, bits, _) in enumerate(specs):
@@ -812,7 +832,7 @@ def _build_batch_executable(specs: tuple, backend: str,
             total = total + jax.vmap(one)(u, v)
         return total
 
-    return run
+    return tracing.jit("tc_intersect_batch", run)
 
 
 def get_batch_executable(specs: tuple, backend: str, interpret: bool,
@@ -878,6 +898,22 @@ def set_cache_limit(maxsize: int) -> int:
 # TrianglePlan — the device-resident, replayable count
 # ---------------------------------------------------------------------------
 
+def _dispatch(fn: Callable, *args):
+    """Enqueue one device call (``tc.dispatch``)."""
+    with tracing.span("tc.dispatch"):
+        out = fn(*args)
+    tracing.bump("tc.dispatches")
+    return out
+
+
+def _sync(result, acc: Callable):
+    """Block on one device result and read it to the host (``tc.sync``)."""
+    with tracing.span("tc.sync"):
+        out = acc(result)
+    tracing.bump("tc.host_syncs")
+    return out
+
+
 @dataclasses.dataclass
 class _Stage:
     executable: Callable
@@ -892,6 +928,14 @@ class _Stage:
     def run(self):
         """One device dispatch over the resident buffers."""
         return self.executable(*self.args)
+
+    def count(self, index: int, acc: Callable):
+        """This stage's partial count: ``run()``, then one host sync;
+        ``acc`` (int or float) reads the result."""
+        with tracing.span("tc.stage", index=index, width=self.shape_key[1],
+                          strategy=self.strategy or "",
+                          rows=self.shape_key[0], chunks=1):
+            return _sync(_dispatch(self.run), acc)
 
 
 @dataclasses.dataclass
@@ -922,7 +966,6 @@ class _TiledStage:
     bitmap_bits: Optional[int] = None
     # host (src, dst) for the chunked per-vertex path (filtered stages only)
     vertex_args: Optional[Tuple[np.ndarray, np.ndarray]] = None
-    float_acc: bool = False  # matrix lane accumulates float partials
     args: Tuple = ()  # no resident device buffers (block_until_ready no-op)
     source: Optional[prep.StreamedBucket] = None  # device-gathered rows
 
@@ -936,32 +979,38 @@ class _TiledStage:
     def num_chunks(self) -> int:
         return -(-self.rows // self.chunk_rows)
 
-    def _iter_chunks(self, arrays, fills):
-        """Upload successive (chunk_rows, ...) slices, tail-padded to the
-        single chunk shape class."""
-        for s in range(0, self.rows, self.chunk_rows):
-            out = []
-            for a, f in zip(arrays, fills):
-                c = a[s:s + self.chunk_rows]
-                if c.shape[0] < self.chunk_rows:
-                    pad = np.full((self.chunk_rows - c.shape[0],)
-                                  + c.shape[1:], f, a.dtype)
-                    c = np.concatenate([c, pad], axis=0)
-                out.append(jnp.asarray(c))
-            yield tuple(out)
+    def _upload(self, arrays, fills, s: int) -> tuple:
+        """Rows ``[s, s + chunk_rows)`` of ``arrays``, tail-padded to the
+        single chunk shape class, uploaded."""
+        out = []
+        for a, f in zip(arrays, fills):
+            c = a[s:s + self.chunk_rows]
+            if c.shape[0] < self.chunk_rows:
+                pad = np.full((self.chunk_rows - c.shape[0],)
+                              + c.shape[1:], f, a.dtype)
+                c = np.concatenate([c, pad], axis=0)
+            out.append(jnp.asarray(c))
+        return tuple(out)
 
-    def run(self):
-        """Stream every chunk through the cached executable; host-side
-        accumulation of the partial counts."""
+    def _run_chunk(self, s: int):
+        """Gather (device rows) or upload (host rows) the chunk at row
+        ``s`` and enqueue its count."""
         if self.source is not None:
-            return sum(int(self.source.count(self.executable, s,
-                                             self.chunk_rows))
-                       for s in range(0, self.rows, self.chunk_rows))
-        total = 0.0 if self.float_acc else 0
-        for chunk_args in self._iter_chunks(self.host_args, self.fills):
-            r = self.executable(*chunk_args)
-            total += float(r) if self.float_acc else int(r)
-        return total
+            return self.source.count(self.executable, s, self.chunk_rows)
+        return self.executable(*self._upload(self.host_args, self.fills, s))
+
+    def count(self, index: int, acc: Callable):
+        """Stream every chunk through the cached executable: one dispatch
+        and one host sync per chunk, partials accumulated on host with
+        ``acc`` (int or float)."""
+        with tracing.span("tc.stage", index=index,
+                          width=self.chunk_shape_key[1],
+                          strategy=self.strategy or "", rows=self.rows,
+                          chunks=self.num_chunks):
+            total = acc(0)
+            for s in range(0, self.rows, self.chunk_rows):
+                total += _sync(_dispatch(self._run_chunk, s), acc)
+            return total
 
     def iter_vertex_chunks(self):
         """Chunked (u, v, src, dst) uploads for the per-vertex path."""
@@ -969,8 +1018,9 @@ class _TiledStage:
             return (self.source.gather(s, self.chunk_rows)
                     for s in range(0, self.rows, self.chunk_rows))
         assert self.vertex_args is not None
-        return self._iter_chunks(self.host_args + tuple(self.vertex_args),
-                                 self.fills + (0, 0))
+        arrays = self.host_args + tuple(self.vertex_args)
+        return (self._upload(arrays, self.fills + (0, 0), s)
+                for s in range(0, self.rows, self.chunk_rows))
 
 
 @dataclasses.dataclass
@@ -995,15 +1045,16 @@ class TrianglePlan:
         """Exact triangle count; pure device replay of the cached stages
         (tiled stages stream their bucket chunk-by-chunk through the same
         cached executables, accumulating partials on host)."""
-        if self.algorithm in ("matrix", "matrix_distributed"):
-            total_f = 0.0
-            for st in self.stages:
-                total_f += float(st.run())
-            total = int(round(total_f))
-        else:
-            total = 0
-            for st in self.stages:
-                total += int(st.run())
+        acc = float if self.algorithm in ("matrix",
+                                          "matrix_distributed") else int
+        with tracing.span("tc.count", algorithm=self.algorithm,
+                          stages=len(self.stages)):
+            tracing.bump("tc.counts")
+            total = acc(0)
+            for i, st in enumerate(self.stages):
+                total += st.count(i, acc)
+        if acc is float:
+            total = int(round(total))
         if self.divisor != 1:
             assert total % self.divisor == 0, total
             total //= self.divisor
@@ -1274,7 +1325,6 @@ def _plan_matrix(g: Graph, block, permute: bool, backend: str,
                 chunk_rows=chunk,
                 shape_key=shape_key,
                 chunk_shape_key=chunk_key,
-                float_acc=True,
             )
             stages.append(st)
             tiled_buckets.append(dict(shape=shape_key, chunk_rows=chunk,

@@ -44,7 +44,6 @@ import functools
 from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from repro.graphs.formats import (
@@ -73,6 +72,7 @@ from repro.graphs.device import (
     fits_int32_pair_keys,
     resolve_edge_key_mode,
 )
+from repro.core import tracing
 from repro.core.options import DEFAULT_WIDTHS
 
 __all__ = [
@@ -165,16 +165,25 @@ class StreamedBucket:
     def count(self, fn, offset: int, rows: int):
         """``fn(u, v)`` over the chunk ``gather(offset, rows)`` would
         return, gathered and counted in one dispatch."""
-        return _gathered_count(fn, *self._window(offset, rows), n=self.n,
-                               rows=int(rows), width=self.width)
+        return _gathered_count(fn)(*self._window(offset, rows), n=self.n,
+                                   rows=int(rows), width=self.width)
 
 
-@functools.partial(jax.jit, static_argnames=("fn", "n", "rows", "width"))
-def _gathered_count(fn, sorted_src, sorted_dst, start, count, nbrs, *,
-                    n: int, rows: int, width: int):
-    u, v, _, _ = _gather_bucket_dev(sorted_src, sorted_dst, start, count,
-                                    nbrs, n=n, e_pad=rows, width=width)
-    return fn(u, v)
+# one entry per chunk executable, bounded as the engine's executable cache is
+# by default
+@functools.lru_cache(maxsize=512)
+def _gathered_count(fn):
+    """``fn(u, v)`` fused with the chunk gather, jitted under ``fn``'s name
+    with ``_gathered`` appended."""
+
+    def gathered(sorted_src, sorted_dst, start, count, nbrs, *,
+                 n: int, rows: int, width: int):
+        u, v, _, _ = _gather_bucket_dev(sorted_src, sorted_dst, start, count,
+                                        nbrs, n=n, e_pad=rows, width=width)
+        return fn(u, v)
+
+    return tracing.jit(f"{fn.__name__}_gathered", gathered,
+                       static_argnames=("n", "rows", "width"))
 
 
 def _as_device_graph(g: Union[Graph, DeviceGraph],
