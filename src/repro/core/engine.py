@@ -956,6 +956,8 @@ class _TiledStage:
     exact integers far below 2^24).
     """
 
+    # the chunk executable; with a ``source``, fused with its gather
+    # (``prep.gathered_count``)
     executable: Callable
     host_args: Tuple[np.ndarray, ...]  # full padded bucket, host-resident
     fills: Tuple[Any, ...]  # tail-chunk fill per host array (inert rows)
@@ -1244,6 +1246,11 @@ def _plan_intersection(g, variant: str, backend: str, interpret: bool,
             fn = get_executable("intersection", backend, interpret,
                                 chunk_key, strategy=strat, bitmap_bits=bits)
             if isinstance(b, prep.StreamedBucket):
+                # the chunk gather fused into the count, cached beside fn
+                fn = _EXECUTABLE_CACHE.get_or_build(
+                    ("intersection_gathered", strat, backend, bool(interpret),
+                     bits, chunk_key),
+                    lambda fn=fn: prep.gathered_count(fn))
                 host_args, vertex_args, source = (), None, b
             else:
                 host_args = (np.asarray(b.u_lists), np.asarray(b.v_lists))

@@ -40,7 +40,6 @@ Lanes:
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -85,6 +84,7 @@ __all__ = [
     "delta_update_buckets",
     "forward_edge_keys_device",
     "forward_edge_keys_host",
+    "gathered_count",
     "induced_device_graph",
     "peel_to_two_core",
     "peel_to_two_core_device",
@@ -162,17 +162,15 @@ class StreamedBucket:
         return _gather_bucket_dev(*self._window(offset, rows), n=self.n,
                                   e_pad=int(rows), width=self.width)
 
-    def count(self, fn, offset: int, rows: int):
-        """``fn(u, v)`` over the chunk ``gather(offset, rows)`` would
-        return, gathered and counted in one dispatch."""
-        return _gathered_count(fn)(*self._window(offset, rows), n=self.n,
-                                   rows=int(rows), width=self.width)
+    def count(self, gathered, offset: int, rows: int):
+        """``gathered`` (a ``gathered_count`` executable) over the chunk
+        ``gather(offset, rows)`` would return, gathered and counted in one
+        dispatch."""
+        return gathered(*self._window(offset, rows), n=self.n,
+                        rows=int(rows), width=self.width)
 
 
-# one entry per chunk executable, bounded as the engine's executable cache is
-# by default
-@functools.lru_cache(maxsize=512)
-def _gathered_count(fn):
+def gathered_count(fn):
     """``fn(u, v)`` fused with the chunk gather, jitted under ``fn``'s name
     with ``_gathered`` appended."""
 

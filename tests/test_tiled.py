@@ -148,3 +148,26 @@ def test_device_streamed_buckets_match_monolithic(g_rmat, algorithm):
     assert streamed and res.meta["num_chunks"] >= 2
     np.testing.assert_array_equal(tiled.triangles_per_vertex(),
                                   mono.triangles_per_vertex())
+
+
+def test_streamed_chunk_executables_live_in_the_engine_cache(g_rmat):
+    """A streamed chunk's fused gather-and-count is an entry of the engine's
+    executable cache, beside its chunk executable: plans of the same shapes
+    share it, and clearing the cache releases it."""
+    from repro.core import engine
+
+    opts = CountOptions(algorithm="intersection", max_device_bytes=1 << 13)
+    a, b = TriangleCounter(g_rmat, opts), TriangleCounter(g_rmat, opts)
+    a.count(), b.count()
+    streamed = [(sa, sb) for sa, sb in zip(a.plan.stages, b.plan.stages)
+                if getattr(sa, "source", None) is not None]
+    assert streamed
+    keys = engine.cache_info()["keys"]
+    for sa, sb in streamed:
+        assert sa.executable is sb.executable
+        key = ("intersection_gathered", sa.strategy, a.plan.backend,
+               a.plan.interpret, sa.bitmap_bits, sa.chunk_shape_key)
+        assert key in keys and engine._EXECUTABLE_CACHE[key] is sa.executable
+    engine.clear_executable_cache()
+    assert not any(k[0] == "intersection_gathered"
+                   for k in engine.cache_info()["keys"])
