@@ -1295,6 +1295,7 @@ def _plan_intersection(g, variant: str, backend: str, interpret: bool,
         bucket_shapes=[s.shape_key for s in stages],
         bucket_strategies=[(s.shape_key[1], s.strategy) for s in stages],
         bucket_edges=[b.edges for b in buckets],
+        neighbor_table_widths=tuple(b.table_width for b in buckets),
         edges=int(sum(b.edges for b in buckets)),
         max_device_bytes=max_device_bytes,
         tiled_buckets=tiled_buckets,

@@ -122,6 +122,12 @@ class DeviceBucket:
     def shape(self) -> tuple:
         return (self.e_pad, self.width)
 
+    @property
+    def table_width(self) -> int:
+        """Columns of the neighbor table the rows were gathered from: every
+        gather takes whole rows."""
+        return int(self.u_lists.shape[1])
+
 
 def bucket_nbytes(e_pad: int, width: int) -> int:
     """Device bytes one gathered intersection bucket costs: the (e, w)
@@ -133,11 +139,12 @@ def bucket_nbytes(e_pad: int, width: int) -> int:
 class StreamedBucket:
     """A degree-class bucket too large to gather whole.
 
-    Its sorted edge endpoints and the shared padded neighbor table stay on
-    device; ``gather(offset, rows)`` materializes rows ``[offset, offset +
-    rows)`` of the ``DeviceBucket`` layout it stands for — (u, v, src, dst),
-    with rows past ``edges`` as whole-row padding. One compiled gather
-    serves every chunk of every same-width bucket of a ``rows`` class.
+    Its sorted edge endpoints and its own (n, width) padded neighbor table
+    stay on device; ``gather(offset, rows)`` materializes rows ``[offset,
+    offset + rows)`` of the ``DeviceBucket`` layout it stands for — (u, v,
+    src, dst), with rows past ``edges`` as whole-row padding. One compiled
+    gather serves every chunk of every same-width bucket of a ``rows``
+    class.
     """
 
     width: int
@@ -153,6 +160,10 @@ class StreamedBucket:
     def shape(self) -> tuple:
         return (self.e_pad, self.width)
 
+    @property
+    def table_width(self) -> int:
+        return int(self.nbrs.shape[1])
+
     def _window(self, offset: int, rows: int) -> tuple:
         return (self.sorted_src, self.sorted_dst,
                 jnp.int32(self.start + offset),
@@ -160,14 +171,14 @@ class StreamedBucket:
 
     def gather(self, offset: int, rows: int):
         return _gather_bucket_dev(*self._window(offset, rows), n=self.n,
-                                  e_pad=int(rows), width=self.width)
+                                  e_pad=int(rows))
 
     def count(self, gathered, offset: int, rows: int):
         """``gathered`` (a ``gathered_count`` executable) over the chunk
         ``gather(offset, rows)`` would return, gathered and counted in one
         dispatch."""
         return gathered(*self._window(offset, rows), n=self.n,
-                        rows=int(rows), width=self.width)
+                        rows=int(rows))
 
 
 def gathered_count(fn):
@@ -175,13 +186,13 @@ def gathered_count(fn):
     with ``_gathered`` appended."""
 
     def gathered(sorted_src, sorted_dst, start, count, nbrs, *,
-                 n: int, rows: int, width: int):
+                 n: int, rows: int):
         u, v, _, _ = _gather_bucket_dev(sorted_src, sorted_dst, start, count,
-                                        nbrs, n=n, e_pad=rows, width=width)
+                                        nbrs, n=n, e_pad=rows)
         return fn(u, v)
 
     return tracing.jit(f"{fn.__name__}_gathered", gathered,
-                       static_argnames=("n", "rows", "width"))
+                       static_argnames=("n", "rows"))
 
 
 def _as_device_graph(g: Union[Graph, DeviceGraph],
@@ -201,6 +212,13 @@ def prepare_intersection_buckets_device(
 ) -> List[Union[DeviceBucket, StreamedBucket]]:
     """Device-resident intersection prep: orientation + bucket layout +
     padded neighbor gathers, all jitted.
+
+    Each bucket gathers whole rows of a padded neighbor table exactly as
+    wide as the bucket (``DeviceGraph.padded_neighbors(width)``, cached;
+    the widest is built first and the narrower are its leading columns):
+    both endpoints of an edge in bucket ``w`` have degree ≤ ``w``, so the
+    table holds their whole lists, and a whole-row gather stays a gather
+    on a TPU.
 
     Args:
       g: a host ``Graph`` (uploaded once) or an existing ``DeviceGraph``.
@@ -247,13 +265,17 @@ def prepare_intersection_buckets_device(
         n=n, num_bounds=len(bounds),
     )
     counts_h = np.asarray(counts)  # one small sync for static extents
-    nbrs = dg.padded_neighbors(bounds[-1], oriented=(variant == "filtered"))
+    oriented = variant == "filtered"
+    # the widest table first: each narrower one is then its leading columns
+    dg.padded_neighbors(max(w for w, c in zip(bounds, counts_h) if c),
+                        oriented=oriented)
 
     out = []
     for i, w in enumerate(bounds):
         c = int(counts_h[i])
         if c == 0:
             continue
+        nbrs = dg.padded_neighbors(w, oriented=oriented)
         e_pad = dg.policy.round_edges(c)
         if max_bucket_bytes is not None \
                 and bucket_nbytes(e_pad, w) > max_bucket_bytes:
@@ -263,8 +285,7 @@ def prepare_intersection_buckets_device(
                 nbrs=nbrs))
             continue
         u, v, sb, db = _gather_bucket_dev(
-            ssrc, sdst, starts[i], counts[i], nbrs,
-            n=n, e_pad=e_pad, width=w,
+            ssrc, sdst, starts[i], counts[i], nbrs, n=n, e_pad=e_pad,
         )
         out.append(DeviceBucket(width=w, edges=c, u_lists=u, v_lists=v,
                                 src=sb, dst=db))

@@ -316,6 +316,12 @@ def _padded_neighbors_dev(src: jnp.ndarray, dst: jnp.ndarray,
     return out.at[src, pos].set(dst.astype(jnp.int32), mode="drop")
 
 
+@functools.partial(jax.jit, static_argnames=("width",))
+def _leading_columns(table: jnp.ndarray, *, width: int) -> jnp.ndarray:
+    """A copy of ``table[:, :width]``."""
+    return table[:, :width]
+
+
 @functools.partial(jax.jit, static_argnames=("n", "num_bounds"))
 def _bucket_sort_dev(src: jnp.ndarray, dst: jnp.ndarray, valid: jnp.ndarray,
                      deg: jnp.ndarray, bounds: jnp.ndarray,
@@ -339,17 +345,21 @@ def _bucket_sort_dev(src: jnp.ndarray, dst: jnp.ndarray, valid: jnp.ndarray,
     return src[order], dst[order], counts, starts
 
 
-@functools.partial(jax.jit, static_argnames=("n", "e_pad", "width"))
+@functools.partial(jax.jit, static_argnames=("n", "e_pad"))
 def _gather_bucket_dev(sorted_src: jnp.ndarray, sorted_dst: jnp.ndarray,
                        start, count, nbrs: jnp.ndarray,
-                       *, n: int, e_pad: int, width: int):
-    """Materialize one bucket's padded (e_pad, width) neighbor-list pair.
+                       *, n: int, e_pad: int):
+    """Materialize one bucket's padded (e_pad, W) neighbor-list pair, W the
+    width of the (n, W) table ``nbrs``.
 
-    Rows past ``count`` are whole-row padding: u = -1, v = -2 (disjoint ⇒
-    zero matches in every intersection core). Within real rows, u keeps the
-    in-row sentinel ``n`` and v's is rewritten to ``n + 1``. Returns
-    (u_lists, v_lists, src, dst); padded rows carry src = dst = 0, which is
-    safe for the per-vertex scatters because their match counts are zero.
+    Each row is a whole row of ``nbrs``: a whole-row gather stays one
+    gather, where a narrower window of a wider table becomes a loop of one
+    row per iteration on a TPU. Rows past ``count`` are whole-row padding:
+    u = -1, v = -2 (disjoint ⇒ zero matches in every intersection core).
+    Within real rows, u keeps the in-row sentinel ``n`` and v's is
+    rewritten to ``n + 1``. Returns (u_lists, v_lists, src, dst); padded
+    rows carry src = dst = 0, which is safe for the per-vertex scatters
+    because their match counts are zero.
     """
     rows = jnp.arange(e_pad)
     bvalid = rows < count
@@ -357,8 +367,8 @@ def _gather_bucket_dev(sorted_src: jnp.ndarray, sorted_dst: jnp.ndarray,
     idx = jnp.clip(start + rows, 0, lim)
     sb = jnp.where(bvalid, sorted_src[idx], 0).astype(jnp.int32)
     db = jnp.where(bvalid, sorted_dst[idx], 0).astype(jnp.int32)
-    u = jnp.where(bvalid[:, None], nbrs[sb, :width], -1).astype(jnp.int32)
-    vfull = nbrs[db, :width]
+    u = jnp.where(bvalid[:, None], nbrs[sb], -1).astype(jnp.int32)
+    vfull = nbrs[db]
     v = jnp.where(
         bvalid[:, None], jnp.where(vfull == n, n + 1, vfull), -2
     ).astype(jnp.int32)
@@ -782,11 +792,18 @@ class DeviceGraph:
         """(n, width) neighbor matrix (in-row sentinel ``n``), cached.
 
         ``oriented=True`` gathers the forward (N⁺) lists; ``False`` the full
-        undirected adjacency rows.
+        undirected adjacency rows. Narrower than a table already cached, it
+        is that table's leading columns, copied out: the scatter would drop
+        the same slots, and a copy compiles in a fraction of the time.
         """
         key = (int(width), bool(oriented))
         if key not in self._nbrs:
-            if oriented:
+            wider = [w for w, o in self._nbrs if o == oriented and w > width]
+            if wider:
+                self._nbrs[key] = _leading_columns(
+                    self._nbrs[(min(wider), bool(oriented))],
+                    width=int(width))
+            elif oriented:
                 fwd = self.forward()
                 self._nbrs[key] = _padded_neighbors_dev(
                     fwd.src, fwd.dst, fwd.kvalid, fwd.row_ptr,

@@ -32,7 +32,7 @@ from repro.graphs.device import (
     ShapePolicy,
     next_pow2,
 )
-from repro.graphs.formats import orient_forward
+from repro.graphs.formats import csr_to_padded_neighbors, orient_forward
 from repro.core import (
     CountOptions,
     GraphBatch,
@@ -78,6 +78,24 @@ def test_device_buckets_match_host(g, variant):
         # whole-row padding uses the repo-wide disjoint sentinels
         assert (np.asarray(db.u_lists)[e:] == -1).all()
         assert (np.asarray(db.v_lists)[e:] == -2).all()
+
+
+@pytest.mark.parametrize("g", ADVERSARIAL, ids=_IDS)
+@pytest.mark.parametrize("oriented", [True, False])
+def test_narrow_neighbor_tables_match_host(g, oriented):
+    """A neighbor table narrower than one already cached (its leading
+    columns) equals the one scattered afresh and the host's, truncated
+    rows included."""
+    base = orient_forward(g) if oriented else g
+    dg = DeviceGraph.from_graph(g)
+    dg.padded_neighbors(16, oriented=oriented)
+    for w in (4, 2, 1):
+        host = csr_to_padded_neighbors(base, pad_to=w, fill=g.n)
+        fresh = DeviceGraph.from_graph(g).padded_neighbors(w,
+                                                           oriented=oriented)
+        np.testing.assert_array_equal(
+            np.asarray(dg.padded_neighbors(w, oriented=oriented)), host)
+        np.testing.assert_array_equal(np.asarray(fresh), host)
 
 
 @pytest.mark.parametrize("g", ADVERSARIAL, ids=_IDS)

@@ -17,8 +17,12 @@ accumulation). This module is the differential harness:
 * ``triangles_per_vertex`` over a tiled filtered plan (the vertex
   executable streams the same chunks);
 * budget semantics: a budget big enough for everything tiles nothing and
-  keys a distinct plan from the unbudgeted options.
+  keys a distinct plan from the unbudgeted options;
+* device prep gathers every bucket, resident or streamed, as whole rows of
+  a neighbor table exactly as wide as the bucket.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -171,3 +175,71 @@ def test_streamed_chunk_executables_live_in_the_engine_cache(g_rmat):
     engine.clear_executable_cache()
     assert not any(k[0] == "intersection_gathered"
                    for k in engine.cache_info()["keys"])
+
+
+@pytest.mark.parametrize("variant", ["filtered", "full"])
+def test_each_bucket_gathers_from_a_table_as_wide_as_itself(
+        g_rmat, variant, monkeypatch):
+    """Device prep gathers each bucket, resident or streamed, from a padded
+    neighbor table exactly as wide as the bucket, and the plan's meta lists
+    those widths; the counts and per-vertex counts still equal the
+    monolithic and host-prep plans' bit for bit."""
+    from repro.core import prep
+
+    gathers = []  # (table width, gathered row width) of every device gather
+    real = prep._gather_bucket_dev
+
+    def recording(*args, **kw):
+        out = real(*args, **kw)
+        gathers.append((int(args[4].shape[1]), int(out[0].shape[1])))
+        return out
+
+    monkeypatch.setattr(prep, "_gather_bucket_dev", recording)
+    opts = dict(algorithm="intersection", variant=variant)
+    tiled = TriangleCounter(g_rmat, CountOptions(max_device_bytes=1 << 15,
+                                                 **opts))
+    res = tiled.count()
+    streamed = [st.source for st in tiled.plan.stages
+                if getattr(st, "source", None) is not None]
+    assert len({s.width for s in streamed}) >= 2, res.meta["bucket_shapes"]
+    for s in streamed:
+        assert s.nbrs.shape == (g_rmat.n, s.width)
+    widths = tuple(w for _, w in res.meta["bucket_shapes"])
+    assert res.meta["neighbor_table_widths"] == widths
+    pv = tiled.triangles_per_vertex()
+    assert gathers and all(t == w for t, w in gathers), gathers
+
+    mono = TriangleCounter(g_rmat, CountOptions(**opts))
+    host = TriangleCounter(g_rmat, CountOptions(
+        prep_backend="host", max_device_bytes=1 << 15, **opts))
+    assert int(res) == int(mono.count()) == int(host.count()) \
+        == int(triangle_count_scipy(g_rmat))
+    assert host.count().meta["neighbor_table_widths"] == widths
+    np.testing.assert_array_equal(pv, mono.triangles_per_vertex())
+    np.testing.assert_array_equal(pv, host.triangles_per_vertex())
+
+
+_GATHER = re.compile(r'"stablehlo\.gather".*slice_sizes = array<i64: '
+                     r'([\d, ]+)>.*?: \(tensor<([\dx]+)x[a-z]\w*>')
+
+
+def test_streamed_chunk_gathers_whole_table_rows(g_rmat):
+    """The fused gather-and-count of a W=128 chunk gathers whole rows of its
+    table: each gather's ``slice_sizes`` spans the operand's trailing
+    dimensions. A narrower window ([1, 128] of a 512-wide table) is what
+    the TPU compiler expands into a loop of one row per iteration."""
+    tc = TriangleCounter(g_rmat, CountOptions(
+        algorithm="intersection", variant="full", strategy="broadcast",
+        max_device_bytes=1 << 15))
+    st = next(st for st in tc.plan.stages
+              if getattr(st, "source", None) is not None
+              and st.source.width == 128)
+    lowered = st.source.count(st.executable.lower, 0, st.chunk_rows)
+    text = lowered.as_text()
+    assert "tc_intersect_broadcast_w128_gathered" in text
+    gathers = [([int(x) for x in sizes.split(",")],
+                [int(x) for x in operand.split("x")])
+               for sizes, operand in _GATHER.findall(text)]
+    assert [1, 128] in [sizes for sizes, _ in gathers], gathers
+    for sizes, dims in gathers:
+        assert sizes[1:] == dims[1:], (sizes, dims)

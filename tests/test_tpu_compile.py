@@ -110,3 +110,30 @@ def test_main_jnp_executable_fits_v5e(one_chip):
     mem = run.lower(rows, rows).compile().memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < _V5E_HBM_BYTES // 2, used
+
+
+def test_streamed_w128_chunk_gathers_without_a_loop(one_chip):
+    """The fused gather-and-count of a W=128 chunk at Graph500 scale 18, as
+    the default budget streams it on a v5e, reads the bucket's own 128-wide
+    neighbor table: its row gathers compile to gathers, and no ``while``
+    loop of one row per iteration comes from them (a 128-wide window of a
+    512-wide table compiles to two such loops)."""
+    from repro.core import engine, prep
+
+    n = 1 << 18
+    run = engine._build_intersect_executable("broadcast", "jnp", False, None)
+    chunk = engine._tile_chunk_rows(
+        1 << 21, prep.bucket_nbytes(1, 128),
+        _V5E_HBM_BYTES // engine._BUCKET_MEMORY_SHARE)
+    assert chunk == 1 << 20
+    edges = _spec(one_chip, (1 << 22,), jnp.int32)
+    scalar = _spec(one_chip, (), jnp.int32)
+    table = _spec(one_chip, (n, 128), jnp.int32)
+    text = prep.gathered_count(run).lower(
+        edges, edges, scalar, scalar, table, n=n, rows=chunk
+    ).compile().as_text()
+    lines = text.splitlines()
+    assert sum(" gather(" in line and "slice_sizes={1,128}" in line
+               for line in lines) == 2
+    loops = [line for line in lines if " while(" in line]
+    assert not any("_gather_bucket_dev" in line for line in loops), loops
