@@ -12,8 +12,12 @@ Phases (one process; every count is checked, and a failed check raises):
 * Pallas kernels — each compiled Pallas core (broadcast, probe and bitmap
   intersection, the masked-SpGEMM matrix kernel, the hash probe) counts a
   graph where it is legal, checked against the ``jnp`` count of that graph;
-* strategy — the ``jnp`` probe and broadcast cores time the same wide
-  rows (W = 128 and 512), the measurement behind the cost model's TPU rule;
+* strategy — the ``jnp`` probe and broadcast cores, counts and masks, time
+  the same wide rows (W = 128 and 512), the measurement behind the TPU
+  rules of ``choose_strategy`` and ``choose_mask_strategy``;
+* per-vertex — ``TriangleCounter.triangles_per_vertex`` and
+  ``clustering_coefficients`` on ``rmat_graph(14, 16)``, checked against
+  the host's scipy counts (diagonal of A³ over 2);
 * serving — a ``TriangleService`` answers a few ``count`` requests, each
   checked against the facade;
 * dynamic update — one ``DynamicTriangleCounter.apply_updates`` batch at
@@ -133,10 +137,18 @@ def phase_pallas(scale: int, seed: int) -> None:
 
 
 def phase_strategy(seed: int, rows: int = 4096, reps: int = 5) -> None:
-    """The rule behind ``choose_strategy`` sending wide ``jnp`` buckets to
-    broadcast on a TPU: both ``jnp`` cores on the same random sorted rows,
-    checked equal, best of ``reps`` warm runs each."""
-    from repro.kernels.intersect.ops import intersect_counts
+    """The rules behind ``choose_strategy`` and ``choose_mask_strategy``
+    sending wide ``jnp`` buckets to broadcast on a TPU: both ``jnp`` cores,
+    as counts and as masks, on the same random sorted rows, checked equal,
+    best of ``reps`` warm runs each."""
+    from repro.kernels.intersect.ops import intersect_counts, intersect_matches
+
+    def counts(a, b, s):
+        return intersect_counts(a, b, strategy=s, backend="jnp").sum()
+
+    def masks(a, b, s):  # one number per run: the matches, weighted by place
+        m = intersect_matches(a, b, strategy=s)
+        return (m * jnp.arange(1, a.shape[1] + 1, dtype=jnp.int32)).sum()
 
     rng = np.random.default_rng(seed)
     log(f"[strategy] jnp probe vs jnp broadcast, {rows} rows, best of {reps}")
@@ -145,22 +157,60 @@ def phase_strategy(seed: int, rows: int = 4096, reps: int = 5) -> None:
         u, v = (jnp.asarray(np.sort(rng.random((rows, 4 * width))
                                     .argsort(axis=1)[:, :width], axis=1)
                             .astype(np.int32)) for _ in range(2))
-        best, got = {}, {}
-        for strategy in ("probe", "broadcast"):
-            fn = jax.jit(lambda a, b, s=strategy: intersect_counts(
-                a, b, strategy=s, backend="jnp").sum())
-            got[strategy] = int(fn(u, v))
-            times = []
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                fn(u, v).block_until_ready()
-                times.append(time.perf_counter() - t0)
-            best[strategy] = min(times)
-        check(f"strategy W={width} probe", got["probe"], got["broadcast"])
-        log(f"  W={width}: probe {best['probe']:.6f}s broadcast "
-            f"{best['broadcast']:.6f}s ratio "
-            f"{best['probe'] / best['broadcast']:.1f}x; {got['probe']} "
-            f"matches both")
+        for core, body in (("count", counts), ("mask", masks)):
+            best, got = {}, {}
+            for strategy in ("probe", "broadcast"):
+                fn = jax.jit(lambda a, b, s=strategy: body(a, b, s))
+                got[strategy] = int(fn(u, v))
+                times = []
+                for _ in range(reps):
+                    t0 = time.perf_counter()
+                    fn(u, v).block_until_ready()
+                    times.append(time.perf_counter() - t0)
+                best[strategy] = min(times)
+            check(f"strategy {core} W={width} probe", got["probe"],
+                  got["broadcast"])
+            log(f"  {core} W={width}: probe {best['probe']:.6f}s broadcast "
+                f"{best['broadcast']:.6f}s ratio "
+                f"{best['probe'] / best['broadcast']:.1f}x; {got['probe']} "
+                f"both")
+
+
+def phase_vertex(scale: int, seed: int) -> None:
+    """Per-vertex counts and clustering coefficients through the session's
+    plan, against the host's scipy counts, and the pass's calls."""
+    import scipy.sparse as sp
+
+    from repro.core import TriangleCounter, tracing
+    from repro.graphs import rmat_graph
+
+    g = rmat_graph(scale, 16, seed=seed + 2)
+    log(f"[vertex] rmat_graph({scale}, 16, seed={seed + 2}): n={g.n} "
+        f"m={g.m_undirected}")
+    a = sp.csr_matrix((np.ones(g.col_idx.shape[0], np.int64), g.col_idx,
+                       g.row_ptr), shape=(g.n, g.n))
+    want = np.asarray((a @ a).multiply(a).sum(axis=1)).ravel() // 2
+    tc = TriangleCounter(g)
+    plan = tc.plan
+    t0 = time.perf_counter()
+    first = plan.triangles_per_vertex()
+    t1 = time.perf_counter()
+    before = tracing.counters()
+    t = plan.triangles_per_vertex()
+    t2 = time.perf_counter()
+    after = tracing.counters()
+    check("vertex sum", int(first.sum()), int(want.sum()))
+    if not (np.array_equal(first, want) and np.array_equal(t, want)):
+        raise AssertionError("vertex: per-vertex counts differ from scipy's")
+    d = g.degrees.astype(np.float64)
+    cc = np.where(d > 1, 2.0 * want / np.maximum(d * (d - 1), 1), 0.0)
+    if not np.array_equal(tc.clustering_coefficients(), cc):
+        raise AssertionError("vertex: clustering coefficients differ")
+    calls = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("tc.vertex_passes", "tc.dispatches", "tc.host_syncs")}
+    log(f"  {tc.algorithm}: buckets {plan.meta.get('bucket_shapes')}; first "
+        f"pass {t1 - t0:.3f}s, replay {t2 - t1:.3f}s; {calls}; "
+        f"{int(want.sum()) // 3} triangles == scipy")
 
 
 def phase_serve(scale: int, seed: int, requests: int = 8) -> None:
@@ -282,6 +332,7 @@ def main(argv=None) -> int:
         phase_main(args.scale, args.seed)
         phase_pallas(12, args.seed)
         phase_strategy(args.seed)
+        phase_vertex(14, args.seed)
         phase_serve(14, args.seed)
         phase_dynamic(16, args.seed)
     log(f"all phases passed in {time.perf_counter() - t0:.3f}s; compile "

@@ -63,6 +63,7 @@ import numpy as np
 
 from repro.core import registry
 from repro.core.engine import (GraphBatch, executable_cache_info,
+                               local_clustering_coefficients,
                                plan_triangle_count)
 from repro.core.options import CountOptions
 from repro.graphs.formats import Graph, normalize_edge_updates
@@ -447,12 +448,12 @@ class TriangleCounter(CounterSession):
         return self._edge_plan().truss_decomposition()
 
     def clustering_coefficients(self) -> np.ndarray:
-        """cc[v] = 2·t(v) / (d(v)·(d(v)−1)); 0 where degree < 2."""
-        t = self.triangles_per_vertex().astype(np.float64)
-        d = self.graph.degrees.astype(np.float64)
-        denom = d * (d - 1.0)
-        with np.errstate(divide="ignore", invalid="ignore"):
-            return np.where(denom > 0, 2.0 * t / denom, 0.0)
+        """cc[v] = 2·t(v) / (d(v)·(d(v)−1)); 0 where degree < 2, in float64
+        (``engine.local_clustering_coefficients``, the formula
+        ``TrianglePlan.clustering_coefficients`` applies) over the
+        session's memoized per-vertex counts."""
+        return local_clustering_coefficients(self.triangles_per_vertex(),
+                                             self.graph.degrees)
 
     def transitivity(self) -> float:
         """3 · #triangles / #wedges (= Σ t(v) / #wedges)."""

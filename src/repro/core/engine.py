@@ -117,6 +117,7 @@ from repro.core.options import DEFAULT_WIDTHS, resolve_interpret
 from repro.core.registry import register_algorithm
 from repro.kernels.intersect.ops import (
     STRATEGIES,
+    choose_mask_strategy,
     choose_strategy,
     intersect_counts,
     intersect_matches,
@@ -370,21 +371,22 @@ def _build_hash_executable(backend: str, interpret: bool,
     return tracing.jit(name, run)
 
 
-def _build_vertex_executable(n: int) -> Callable:
-    """Per-vertex triangle counts for one filtered-intersection bucket.
+def _build_vertex_executable(n: int, name: str = "run") -> Callable:
+    """Per-vertex triangle counts for one filtered-intersection bucket,
+    added to ``acc``.
 
     ``intersect_matches`` (the mask form of the set-intersection core) marks
     which u-list entries appear in both forward neighbor lists; each match
     (e, w) is one triangle (src[e], dst[e], w), so three segment_sums
     attribute it to its three vertices. Padding never matches (disjoint u/v
-    sentinels), so the clip on the scatter ids is safe.
+    sentinels), so the clip on the scatter ids is safe. ``acc`` is the (n,)
+    int32 sum of the pass so far (zeros where the host accumulates).
     """
 
-    @jax.jit
-    def run(u_lists, v_lists, src, dst):
+    def run(u_lists, v_lists, src, dst, acc):
         matched = intersect_matches(u_lists, v_lists)  # (E, W) bool
         per_edge = matched.sum(axis=1, dtype=jnp.int32)
-        t = jax.ops.segment_sum(per_edge, src, num_segments=n)
+        t = acc + jax.ops.segment_sum(per_edge, src, num_segments=n)
         t = t + jax.ops.segment_sum(per_edge, dst, num_segments=n)
         w_ids = jnp.clip(u_lists.reshape(-1), 0, n - 1)
         t = t + jax.ops.segment_sum(
@@ -392,7 +394,7 @@ def _build_vertex_executable(n: int) -> Callable:
         )
         return t
 
-    return run
+    return tracing.jit(name, run)
 
 
 def _build_edge_executable(strategy: str, bitmap_bits: Optional[int],
@@ -692,20 +694,23 @@ def _build_dist_edge_executable(strategy: str, bitmap_bits: Optional[int],
     return run
 
 
-# a count lane's executable is named by the lane, its strategy and the
-# bucket width ("w") or tile block ("b") in its shape key's second place
+# a count lane's (or the per-vertex) executable is named by the lane, its
+# strategy and the bucket width ("w") or tile block ("b") in its shape key's
+# second place
 _COUNT_LANES = {"intersection": ("intersect", "w"),
                 "subgraph": ("intersect", "w"),
                 "hash": ("hash", "w"),
                 "matrix": ("matrix", "b"),
                 "intersection_distributed": ("intersect_dist", "w"),
-                "matrix_distributed": ("matrix_dist", "b")}
+                "matrix_distributed": ("matrix_dist", "b"),
+                "vertex": ("vertex", "w")}
 
 
 def _executable_name(algorithm: str, strategy: Optional[str],
                      shape_key: tuple) -> Optional[str]:
-    """The name a device profile shows for a count lane's executable, e.g.
-    ``tc_intersect_broadcast_w512``; None for the other lanes."""
+    """The name a device profile shows for a count lane's or a per-vertex
+    executable, e.g. ``tc_intersect_broadcast_w512``, ``tc_vertex_w512``;
+    None for the other lanes."""
     if algorithm not in _COUNT_LANES:
         return None
     lane, axis = _COUNT_LANES[algorithm]
@@ -783,7 +788,7 @@ def get_executable(algorithm: str, backend: str, interpret: bool,
             strategy, backend, interpret, bitmap_bits, name),
         "matrix": lambda: _build_matrix_executable(backend, interpret, name),
         "hash": lambda: _build_hash_executable(backend, interpret, name),
-        "vertex": lambda: _build_vertex_executable(int(shape_key[-1])),
+        "vertex": lambda: _build_vertex_executable(int(shape_key[-1]), name),
         "edge": lambda: _build_edge_executable(
             strategy, bitmap_bits, tuple(shape_key)),
         "dynamic_step": lambda: _build_dynamic_step_executable(
@@ -937,6 +942,14 @@ class _Stage:
                           rows=self.shape_key[0], chunks=1):
             return _sync(_dispatch(self.run), acc)
 
+    def vertex_calls(self, n: int) -> List[Callable]:
+        """The per-vertex pass over the resident buffers: one call, taking
+        the pass's (n,) running counts and returning them with this stage's
+        added."""
+        e, w = self.shape_key
+        fn = get_executable("vertex", "jnp", False, (e, w, n))
+        return [lambda acc: fn(*self.args, *self.vertex_args, acc)]
+
 
 @dataclasses.dataclass
 class _TiledStage:
@@ -1014,15 +1027,42 @@ class _TiledStage:
                 total += _sync(_dispatch(self._run_chunk, s), acc)
             return total
 
-    def iter_vertex_chunks(self):
-        """Chunked (u, v, src, dst) uploads for the per-vertex path."""
+    def vertex_calls(self, n: int) -> List[Callable]:
+        """The per-vertex pass, one call per chunk through the cached
+        ``tc_vertex_w<W>`` executable: fused with the chunk's gather
+        (``tc_vertex_w<W>_gathered``) on device rows, after the chunk's
+        upload on host rows. Each takes the pass's (n,) running counts and
+        returns them with the chunk's added."""
+        e, w = self.chunk_shape_key
+        fn = get_executable("vertex", "jnp", False, (e, w, n))
+        starts = range(0, self.rows, self.chunk_rows)
         if self.source is not None:
-            return (self.source.gather(s, self.chunk_rows)
-                    for s in range(0, self.rows, self.chunk_rows))
+            gathered = _EXECUTABLE_CACHE.get_or_build(
+                ("vertex_gathered", None, "jnp", False, None, (e, w, n)),
+                lambda: prep.gathered_count(fn, endpoints=True))
+            return [lambda acc, s=s: self.source.count(
+                gathered, s, self.chunk_rows, acc) for s in starts]
         assert self.vertex_args is not None
         arrays = self.host_args + tuple(self.vertex_args)
-        return (self._upload(arrays, self.fills + (0, 0), s)
-                for s in range(0, self.rows, self.chunk_rows))
+        fills = self.fills + (0, 0)
+        return [lambda acc, s=s: fn(*self._upload(arrays, fills, s), acc)
+                for s in starts]
+
+
+# per-vertex counts accumulate on device in int32 while every vertex's
+# count fits: t(v) <= C(d(v), 2) < 2**31 for undirected degree d(v) <= 2**16
+VERTEX_DEVICE_MAX_DEGREE = 1 << 16
+
+
+def local_clustering_coefficients(t: np.ndarray,
+                                  degrees: np.ndarray) -> np.ndarray:
+    """LDBC Graphalytics' LCC in float64: 2·t(v) / (d(v)·(d(v)−1)), 0 where
+    d(v) < 2, from exact per-vertex counts ``t`` and undirected degrees."""
+    t = np.asarray(t).astype(np.float64)
+    d = np.asarray(degrees).astype(np.float64)
+    denom = d * (d - 1.0)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        return np.where(denom > 0, 2.0 * t / denom, 0.0)
 
 
 @dataclasses.dataclass
@@ -1042,6 +1082,10 @@ class TrianglePlan:
     meta: Dict[str, Any]
     prep_seconds: float
     executions: int = 0
+    # (n,) undirected degrees of the planned graph: the per-vertex pass's
+    # accumulation bound and the clustering coefficients' denominators
+    degrees: Optional[np.ndarray] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     def count(self) -> int:
         """Exact triangle count; pure device replay of the cached stages
@@ -1090,6 +1134,13 @@ class TrianglePlan:
         pruned graph scatter back through ``meta["vertex_map"]``; peeled
         vertices are in no triangle by construction).
 
+        One pass (``tc.vertex``, bumps ``tc.vertex_passes``) dispatches one
+        ``tc_vertex_w<W>`` call per resident stage or streamed chunk. While
+        the graph's max degree is at most ``VERTEX_DEVICE_MAX_DEGREE`` every
+        count fits int32, so the calls add into one device vector and the
+        host reads it once; past it each call's partial is read and summed
+        in int64 on the host.
+
         Returns:
           (n,) int64 numpy array, t[v] = number of triangles containing v.
 
@@ -1109,23 +1160,46 @@ class TrianglePlan:
                 f"not carry them"
             )
         n_local = int(self.meta.get("vertex_n", self.meta["n"]))
+        on_device = self.degrees is not None \
+            and int(self.degrees.max(initial=0)) <= VERTEX_DEVICE_MAX_DEGREE
+        zeros = jnp.zeros(n_local, jnp.int32)
         total = np.zeros(n_local, dtype=np.int64)
-        for st in self.stages:
-            if isinstance(st, _TiledStage):
-                e, w = st.chunk_shape_key
-                fn = get_executable("vertex", "jnp", False, (e, w, n_local))
-                for chunk_args in st.iter_vertex_chunks():
-                    total += np.asarray(fn(*chunk_args), dtype=np.int64)
-                continue
-            e, w = st.shape_key
-            fn = get_executable("vertex", "jnp", False, (e, w, n_local))
-            total += np.asarray(fn(*st.args, *st.vertex_args), dtype=np.int64)
+        acc = zeros
+
+        def read(r):
+            return np.asarray(r, dtype=np.int64)
+
+        with tracing.span("tc.vertex", algorithm=self.algorithm,
+                          stages=len(self.stages)):
+            tracing.bump("tc.vertex_passes")
+            for i, st in enumerate(self.stages):
+                calls = st.vertex_calls(n_local)
+                rows, width = st.shape_key[:2]
+                with tracing.span("tc.stage", index=i, width=width,
+                                  strategy=choose_mask_strategy(width),
+                                  rows=rows, chunks=len(calls)):
+                    for call in calls:
+                        if on_device:
+                            acc = _dispatch(call, acc)
+                        else:
+                            total += _sync(_dispatch(call, zeros), read)
+            if on_device:
+                total = _sync(acc, read)
         vertex_map = self.meta.get("vertex_map")
         if vertex_map is not None:  # subgraph lane: pruned ids -> original
             out = np.zeros(int(self.meta["n"]), dtype=np.int64)
             out[np.asarray(vertex_map)] = total
             return out
         return total
+
+    def clustering_coefficients(self, t: Optional[np.ndarray] = None
+                                ) -> np.ndarray:
+        """(n,) float64 local clustering coefficients
+        (``local_clustering_coefficients``) of the per-vertex counts ``t``,
+        or of a fresh ``triangles_per_vertex()`` pass when None."""
+        if t is None:
+            t = self.triangles_per_vertex()
+        return local_clustering_coefficients(t, self.degrees)
 
     def block_until_ready(self) -> "TrianglePlan":
         """Force all device buffers resident (useful before timing counts)."""
@@ -1786,6 +1860,7 @@ def plan_triangle_count(
         divisor=divisor,
         meta=meta,
         prep_seconds=prep_seconds,
+        degrees=g.degrees,
     )
 
 

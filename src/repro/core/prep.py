@@ -140,11 +140,11 @@ class StreamedBucket:
     """A degree-class bucket too large to gather whole.
 
     Its sorted edge endpoints and its own (n, width) padded neighbor table
-    stay on device; ``gather(offset, rows)`` materializes rows ``[offset,
-    offset + rows)`` of the ``DeviceBucket`` layout it stands for — (u, v,
-    src, dst), with rows past ``edges`` as whole-row padding. One compiled
-    gather serves every chunk of every same-width bucket of a ``rows``
-    class.
+    stay on device; ``count(gathered, offset, rows)`` materializes rows
+    ``[offset, offset + rows)`` of the ``DeviceBucket`` layout it stands for
+    — (u, v, src, dst), with rows past ``edges`` as whole-row padding —
+    inside the executable that consumes them. One compiled gather serves
+    every chunk of every same-width bucket of a ``rows`` class.
     """
 
     width: int
@@ -169,26 +169,25 @@ class StreamedBucket:
                 jnp.int32(self.start + offset),
                 jnp.int32(max(0, min(rows, self.edges - offset))), self.nbrs)
 
-    def gather(self, offset: int, rows: int):
-        return _gather_bucket_dev(*self._window(offset, rows), n=self.n,
-                                  e_pad=int(rows))
-
-    def count(self, gathered, offset: int, rows: int):
-        """``gathered`` (a ``gathered_count`` executable) over the chunk
-        ``gather(offset, rows)`` would return, gathered and counted in one
-        dispatch."""
-        return gathered(*self._window(offset, rows), n=self.n,
+    def count(self, gathered, offset: int, rows: int, *extra):
+        """``gathered`` (a ``gathered_count`` executable) over the chunk at
+        ``offset``, gathered and counted in one dispatch; ``extra`` follows
+        the chunk's arguments."""
+        return gathered(*self._window(offset, rows), *extra, n=self.n,
                         rows=int(rows))
 
 
-def gathered_count(fn):
-    """``fn(u, v)`` fused with the chunk gather, jitted under ``fn``'s name
-    with ``_gathered`` appended."""
+def gathered_count(fn, endpoints: bool = False):
+    """``fn(u, v)`` — with ``endpoints``, ``fn(u, v, src, dst, *extra)`` —
+    fused with the chunk gather, jitted under ``fn``'s name with
+    ``_gathered`` appended."""
 
-    def gathered(sorted_src, sorted_dst, start, count, nbrs, *,
+    def gathered(sorted_src, sorted_dst, start, count, nbrs, *extra,
                  n: int, rows: int):
-        u, v, _, _ = _gather_bucket_dev(sorted_src, sorted_dst, start, count,
-                                        nbrs, n=n, e_pad=rows)
+        u, v, src, dst = _gather_bucket_dev(sorted_src, sorted_dst, start,
+                                            count, nbrs, n=n, e_pad=rows)
+        if endpoints:
+            return fn(u, v, src, dst, *extra)
         return fn(u, v)
 
     return tracing.jit(f"{fn.__name__}_gathered", gathered,

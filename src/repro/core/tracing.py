@@ -1,4 +1,5 @@
-"""Spans and counters on the count path, on the JAX profiler's clock.
+"""Spans and counters on the count and per-vertex paths, on the JAX
+profiler's clock.
 
 ``span(name, **attrs)`` is a ``jax.profiler.TraceAnnotation``: free of any
 record unless a profiler is running (about a microsecond per span), and
@@ -18,6 +19,15 @@ The count replay (``TrianglePlan.count``) marks:
   ``tc.dispatches``;
 * ``tc.sync`` — the host blocked reading one result; bumps
   ``tc.host_syncs``.
+
+The per-vertex replay (``TrianglePlan.triangles_per_vertex``) marks:
+
+* ``tc.vertex`` — one pass (``algorithm``, ``stages``); bumps
+  ``tc.vertex_passes``;
+* inside it the same ``tc.stage`` (``strategy`` is the mask core's),
+  ``tc.dispatch`` per resident stage or streamed chunk, and ``tc.sync``:
+  once per pass while the counts add up on the device, once per call past
+  the int32 bound.
 
 ``jit(name, fn)`` compiles ``fn`` as the module ``jit_<name>``: the name a
 device profile gives the executable's operations.

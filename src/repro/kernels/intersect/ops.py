@@ -24,7 +24,11 @@ probe for wide buckets (W ≥ 64, past the O(W²)/O(W log W) crossover measured
 on the CPU), broadcast for narrow ones — and broadcast for the ``jnp`` core
 on a TPU, where the vmapped ``searchsorted`` probe is gather-bound. ``resolve_strategy`` additionally
 picks the bitmap capacity; the engine applies it per degree bucket and bakes
-the result into the executable-cache key.
+the result into the executable-cache key. ``choose_mask_strategy`` is the
+same rule for the mask entry points (``intersect_matches``, the per-vertex
+and per-edge executables), with bitmap allowed to a wider id range and the
+same TPU exception. The ``jnp`` broadcast count and masks share one row
+chunking (``_row_chunked``), which bounds the (E, W, W) compare tensor.
 
 Sentinel-padding rules (repo-wide): within a row, u pads with one value and
 v with a different one (the engine uses ``n`` and ``n + 1``); whole padding
@@ -216,7 +220,8 @@ def intersect_matches(
     the engine's "vertex" and "edge" analysis executables, which need to
     know WHICH common neighbor matched so they can scatter the triangle to
     its three vertices / three edges. All three strategies apply: broadcast
-    (eq-any over the (E, W, W) compare tensor), probe (searchsorted), and
+    (eq-any over the (E, W, W) compare tensor, row-chunked as the count
+    core's), probe (searchsorted), and
     bitmap (pack v, gather-test each u element — the TRUST-style core,
     picked by "auto" exactly when the id range fits the packed width).
 
@@ -224,8 +229,8 @@ def intersect_matches(
       u_lists: (E, W) int32, each row a sorted neighbor list padded with a
         sentinel disjoint from v's.
       v_lists: (E, W) int32, same layout, disjoint padding sentinel.
-      strategy: "auto" | "broadcast" | "probe" | "bitmap" — the same cost
-        model as ``intersect_counts`` (``choose_strategy``).
+      strategy: "auto" | "broadcast" | "probe" | "bitmap" — "auto" applies
+        ``choose_mask_strategy``.
       bitmap_bits: static bitmap capacity for strategy="bitmap"; must cover
         the id range for exact agreement with the other strategies.
 
@@ -236,7 +241,7 @@ def intersect_matches(
     strategy, bitmap_bits = _resolve_mask_args(u_lists, v_lists,
                                                strategy, bitmap_bits)
     if strategy == "broadcast":
-        return (u_lists[:, :, None] == v_lists[:, None, :]).any(axis=2)
+        return _row_chunked(_matches_broadcast, u_lists, v_lists)
     if strategy == "bitmap":
         return intersect_matches_bitmap(u_lists, v_lists,
                                         num_bits=int(bitmap_bits))
@@ -256,8 +261,8 @@ def intersect_matches_both(
     positions occur in v and which v-list positions occur in u. For every
     common element there is exactly one True in each mask (rows are
     deduplicated neighbor lists), so both masks row-sum to the same
-    per-edge intersection sizes. The broadcast core shares one (E, W, W)
-    eq tensor between the two reductions; probe and bitmap each run two
+    per-edge intersection sizes. The broadcast core shares each row
+    block's eq tensor between the two reductions; probe and bitmap each run two
     passes with the roles swapped. The engine's "edge" executables consume
     both masks to group triangle contributions by u-row and v-row
     respectively.
@@ -265,8 +270,7 @@ def intersect_matches_both(
     strategy, bitmap_bits = _resolve_mask_args(u_lists, v_lists,
                                                strategy, bitmap_bits)
     if strategy == "broadcast":
-        eq = u_lists[:, :, None] == v_lists[:, None, :]
-        return eq.any(axis=2), eq.any(axis=1)
+        return _row_chunked(_matches_both_broadcast, u_lists, v_lists)
     if strategy == "bitmap":
         bits = int(bitmap_bits)
         return (intersect_matches_bitmap(u_lists, v_lists, num_bits=bits),
@@ -274,7 +278,7 @@ def intersect_matches_both(
     return _probe_mask(u_lists, v_lists), _probe_mask(v_lists, u_lists)
 
 
-def choose_mask_strategy(width: int, id_range=None) -> str:
+def choose_mask_strategy(width: int, id_range=None, platform=None) -> str:
     """The ``strategy="auto"`` cost model for MASK consumers
     (``intersect_matches`` / ``intersect_matches_both``).
 
@@ -286,10 +290,16 @@ def choose_mask_strategy(width: int, id_range=None) -> str:
     the crossover sits near B ≈ 4·W packed bits, which is the bound used
     here (capped by ``BITMAP_MAX_BITS`` as everywhere).
 
+    The masks run on the ``jnp`` cores only, so the count rule's TPU
+    exception holds for every width: the vmapped ``searchsorted`` is
+    gather-bound there, and the ``strategy`` phase of ``chip_smoke.py``
+    times the probe and broadcast masks beside the counts.
+
     Args:
       width: the bucket's padded list width W (static).
       id_range: number of distinct ids the lists may contain (the engine
         passes ``n + 2``); None (e.g. under tracing) disqualifies bitmap.
+      platform: where it runs; None means JAX's default backend.
 
     Returns:
       "bitmap" | "probe" | "broadcast".
@@ -298,6 +308,8 @@ def choose_mask_strategy(width: int, id_range=None) -> str:
         bits = _ceil32(id_range)
         if bits <= BITMAP_MAX_BITS and bits <= 4 * packed_bits(width):
             return "bitmap"
+    if (platform or jax.default_backend()) == "tpu":
+        return "broadcast"
     if width >= _PROBE_MIN_WIDTH:
         return "probe"
     return "broadcast"
@@ -357,19 +369,39 @@ def _pad_rows(u_lists, v_lists, tile_edges: int):
 _BROADCAST_CHUNK_ELEMS = 1 << 24
 
 
-@jax.jit
-def _broadcast_jnp(u_lists, v_lists):
-    """jnp broadcast-compare, chunked over rows to bound the (E, W, W)
-    compare tensor (same algorithm as the pallas broadcast kernel)."""
+def _row_chunked(core, u_lists, v_lists):
+    """``core(u, v)`` over blocks of rows, under ``lax.map``, so that at most
+    ``_BROADCAST_CHUNK_ELEMS`` compares live at once. ``core`` returns
+    arrays (or a tuple of them) whose leading axis is the block's rows;
+    rows padded to fill the last block are cut off again."""
     e, w = u_lists.shape
     chunk = int(max(1, min(max(e, 1), _BROADCAST_CHUNK_ELEMS // max(w * w, 1))))
     u_lists, v_lists, e, pad = _pad_rows(u_lists, v_lists, chunk)
     uc = u_lists.reshape(-1, chunk, w)
     vc = v_lists.reshape(-1, chunk, w)
-    out = jax.lax.map(
-        lambda ab: intersect_counts_ref(ab[0], ab[1]), (uc, vc)
-    ).reshape(-1)
-    return out[:e] if pad else out
+    out = jax.lax.map(lambda ab: core(ab[0], ab[1]), (uc, vc))
+
+    def rows(x):
+        x = x.reshape((-1,) + x.shape[2:])
+        return x[:e] if pad else x
+
+    return jax.tree.map(rows, out)
+
+
+@jax.jit
+def _broadcast_jnp(u_lists, v_lists):
+    """jnp broadcast-compare counts, chunked over rows to bound the (E, W, W)
+    compare tensor (same algorithm as the pallas broadcast kernel)."""
+    return _row_chunked(intersect_counts_ref, u_lists, v_lists)
+
+
+def _matches_broadcast(u_lists, v_lists):
+    return (u_lists[:, :, None] == v_lists[:, None, :]).any(axis=2)
+
+
+def _matches_both_broadcast(u_lists, v_lists):
+    eq = u_lists[:, :, None] == v_lists[:, None, :]
+    return eq.any(axis=2), eq.any(axis=1)
 
 
 def _auto_id_range(u_lists, v_lists):

@@ -137,3 +137,35 @@ def test_streamed_w128_chunk_gathers_without_a_loop(one_chip):
                for line in lines) == 2
     loops = [line for line in lines if " while(" in line]
     assert not any("_gather_bucket_dev" in line for line in loops), loops
+
+
+@pytest.mark.parametrize("width", [512, 1024])
+def test_vertex_chunk_runs_the_broadcast_mask_at_scale_20(one_chip,
+                                                          monkeypatch, width):
+    """The fused gather and per-vertex pass of one streamed chunk at
+    Graph500 scale 20, as the default budget streams it on a v5e: the mask
+    rule takes the row-chunked broadcast core on a TPU (no ``searchsorted``
+    from the probe core), and its temporaries fit beside the neighbor
+    tables the plan keeps (n × 4 B × (8 + 32 + 128 + 512 + 1024))."""
+    from repro.core import engine, prep
+
+    # the rule asks JAX's default backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 1 << 20
+    chunk = engine._tile_chunk_rows(
+        1 << 24, prep.bucket_nbytes(1, width),
+        _V5E_HBM_BYTES // engine._BUCKET_MEMORY_SHARE)
+    assert chunk == (1 << 18 if width == 512 else 1 << 17)
+    run = prep.gathered_count(
+        engine._build_vertex_executable(n, f"tc_vertex_w{width}"),
+        endpoints=True)
+    edges = _spec(one_chip, (1 << 24,), jnp.int32)
+    scalar = _spec(one_chip, (), jnp.int32)
+    compiled = run.lower(
+        edges, edges, scalar, scalar, _spec(one_chip, (n, width), jnp.int32),
+        _spec(one_chip, (n,), jnp.int32), n=n, rows=chunk).compile()
+    text = compiled.as_text()
+    assert "searchsorted" not in text and "reduce_or" in text
+    tables = n * 4 * (8 + 32 + 128 + 512 + 1024)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < _V5E_HBM_BYTES - tables - (1 << 30), temp

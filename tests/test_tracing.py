@@ -5,6 +5,10 @@
 * a profile recorded around ``count()`` nests ``tc.count`` ⊃ ``tc.stage`` ⊃
   ``tc.dispatch`` / ``tc.sync`` with their attributes, and names the
   executables by lane, strategy and width;
+* a per-vertex pass bumps ``tc.vertex_passes`` once, makes one dispatch per
+  resident stage and streamed chunk and one host sync in all (one per call
+  past the int32 bound), and its profile nests ``tc.vertex`` ⊃ ``tc.stage``
+  ⊃ ``tc.dispatch`` over the ``tc_vertex_w<W>`` executables;
 * the counters are also ``jax.monitoring`` events.
 """
 
@@ -88,7 +92,11 @@ def test_executables_are_named_by_lane_strategy_and_width():
         == "tc_intersect_broadcast_w512"
     assert engine._executable_name("matrix", None, (4, 64, 64)) \
         == "tc_matrix_b64"
-    assert engine._executable_name("vertex", None, (8, 8, 100)) is None
+    assert engine._executable_name("vertex", None, (8, 8, 100)) \
+        == "tc_vertex_w8"
+    assert engine._executable_name("edge", "probe", (8, 8, 5, 101)) is None
+    vertex = engine.get_executable("vertex", "jnp", False, (16, 8, 100))
+    assert vertex.__name__ == "tc_vertex_w8"
     fn = engine.get_executable("intersection", "jnp", False, (16, 8),
                                strategy="probe")
     assert fn.__name__ == "tc_intersect_probe_w8"
@@ -141,4 +149,65 @@ def test_profile_nests_count_stage_dispatch_sync(g_rmat, tmp_path):
     for leaf in by["tc.dispatch"] + by["tc.sync"]:
         assert sum(_inside(leaf, s) for s in stages) == 1
     assert any(m.startswith("jit_tc_intersect_") and m.endswith("_gathered")
+               for m in modules), modules
+
+
+@pytest.mark.parametrize("prep_backend", ["device", "host"])
+def test_vertex_pass_dispatches_per_chunk_and_syncs_once(g_rmat,
+                                                         prep_backend):
+    tc = TriangleCounter(g_rmat, CountOptions(
+        algorithm="intersection", prep_backend=prep_backend,
+        max_device_bytes=1 << 13))
+    first = tc.count()
+    chunks = first.meta["num_chunks"]
+    assert chunks >= 2
+    resident = len(tc.plan.stages) - len(first.meta["tiled_buckets"])
+    want = tc.plan.triangles_per_vertex()
+    before = tracing.counters()
+    for _ in range(2):
+        assert (tc.plan.triangles_per_vertex() == want).all()
+    after = tracing.counters()
+    assert _delta(before, after, "tc.vertex_passes") == 2
+    assert _delta(before, after, "tc.dispatches") == 2 * (resident + chunks)
+    assert _delta(before, after, "tc.host_syncs") == 2
+    assert _delta(before, after, "tc.counts") == 0
+
+
+def test_vertex_pass_past_the_int32_bound_syncs_per_call(g_rmat):
+    tc = TriangleCounter(g_rmat, CountOptions(algorithm="intersection"))
+    want = tc.plan.triangles_per_vertex()
+    calls = len(tc.plan.stages)
+    tc.plan.degrees = tc.plan.degrees.copy()
+    tc.plan.degrees[0] = engine.VERTEX_DEVICE_MAX_DEGREE + 1
+    before = tracing.counters()
+    assert (tc.plan.triangles_per_vertex() == want).all()
+    after = tracing.counters()
+    assert _delta(before, after, "tc.dispatches") == calls
+    assert _delta(before, after, "tc.host_syncs") == calls
+
+
+def test_profile_nests_vertex_stage_dispatch(g_rmat, tmp_path):
+    tc = TriangleCounter(g_rmat, CountOptions(algorithm="intersection",
+                                              max_device_bytes=1 << 13))
+    first = tc.count()
+    tc.plan.triangles_per_vertex()
+    spans, modules = _profile(tmp_path, tc.plan.triangles_per_vertex)
+    by = {n: [s for s in spans if s[0] == n]
+          for n in ("tc.vertex", "tc.stage", "tc.dispatch", "tc.sync",
+                    "tc.count")}
+    assert not by["tc.count"]
+    vertex, = by["tc.vertex"]
+    assert vertex[3] == {"algorithm": "intersection",
+                         "stages": len(tc.plan.stages)}
+    stages = by["tc.stage"]
+    assert all(_inside(s, vertex) for s in stages)
+    assert sum(s[3]["chunks"] for s in stages) == first.meta["num_chunks"]
+    assert [s[3]["strategy"] for s in stages] == [
+        "broadcast" if w < 64 else "probe" for w in
+        (s[3]["width"] for s in stages)]
+    assert len(by["tc.dispatch"]) == first.meta["num_chunks"]
+    sync, = by["tc.sync"]
+    assert _inside(sync, vertex) and not any(_inside(sync, s)
+                                             for s in stages)
+    assert any(m.startswith("jit_tc_vertex_w") and m.endswith("_gathered")
                for m in modules), modules
