@@ -17,7 +17,9 @@ Phases (one process; every count is checked, and a failed check raises):
   rules of ``choose_strategy`` and ``choose_mask_strategy``;
 * per-vertex — ``TriangleCounter.triangles_per_vertex`` and
   ``clustering_coefficients`` on ``rmat_graph(14, 16)``, checked against
-  the host's scipy counts (diagonal of A³ over 2);
+  the host's scipy counts (diagonal of A³ over 2); then one W=512 chunk at
+  the LCC cell's shapes, the per-vertex executable timed against the
+  whole-mask scatter it replaced (checked equal), and its grouping alone;
 * serving — a ``TriangleService`` answers a few ``count`` requests, each
   checked against the facade;
 * dynamic update — one ``DynamicTriangleCounter.apply_updates`` batch at
@@ -211,6 +213,86 @@ def phase_vertex(scale: int, seed: int) -> None:
     log(f"  {tc.algorithm}: buckets {plan.meta.get('bucket_shapes')}; first "
         f"pass {t1 - t0:.3f}s, replay {t2 - t1:.3f}s; {calls}; "
         f"{int(want.sum()) // 3} triangles == scipy")
+    vertex_chunk_timings(seed)
+
+
+def _best(fn, *args, reps: int = 3):
+    """(result, best of ``reps`` warm seconds) of ``fn(*args)``."""
+    out = jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return out, min(times)
+
+
+def _scatter_vertex(n: int):
+    """The per-vertex body before the credit was grouped by source run:
+    every E·W mask slot scattered to its third vertex."""
+
+    def run(u, v, src, dst, acc):
+        from repro.kernels.intersect.ops import intersect_matches
+
+        matched = intersect_matches(u, v)
+        per_edge = matched.sum(axis=1, dtype=jnp.int32)
+        t = acc + jax.ops.segment_sum(per_edge, src, num_segments=n)
+        t = t + jax.ops.segment_sum(per_edge, dst, num_segments=n)
+        return t + jax.ops.segment_sum(
+            matched.reshape(-1).astype(jnp.int32),
+            jnp.clip(u.reshape(-1), 0, n - 1), num_segments=n)
+
+    run.__name__ = "scatter_vertex"
+    return run
+
+
+def vertex_chunk_timings(seed: int, n: int = 1 << 20, rows: int = 1 << 18,
+                         width: int = 512, sources: int = 40960) -> None:
+    """One W=512 chunk at the LCC cell's shapes (2^18 rows, n = 2^20, ~40k
+    distinct sorted sources as its busiest chunks hold): the engine's
+    ``tc_vertex_w512_gathered``, which credits by source run, against the
+    whole-mask scatter it replaced, checked equal, and its grouping alone."""
+    from repro.core import engine, prep
+    from repro.graphs.device import _gather_bucket_dev
+    from repro.kernels.intersect.ops import intersect_matches
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    # sorted rows over a narrow id range, so that rows share ids and match
+    nbrs = jnp.sort(jax.random.randint(keys[0], (n, width), 0, 4 * width,
+                                       jnp.int32), axis=1)
+    step = n // sources
+    src = jnp.sort(jax.random.randint(keys[1], (rows,), 0, sources,
+                                      jnp.int32)) * step
+    dst = jax.random.randint(keys[2], (rows,), 0, n, jnp.int32)
+    start, count = jnp.int32(0), jnp.int32(rows)
+    args = (src, dst, start, count, nbrs, jnp.zeros(n, jnp.int32))
+    log(f"[vertex chunk] W={width}, {rows} rows, n={n}, "
+        f"{int(jnp.unique(src).shape[0])} distinct sources")
+
+    forms = {"grouped": engine._build_vertex_executable(n, "grouped"),
+             "scatter": _scatter_vertex(n)}
+    got, best = {}, {}
+    for name, fn in forms.items():
+        run = prep.gathered_count(fn, endpoints=True)
+        got[name], best[name] = _best(
+            lambda *a, run=run: run(*a, n=n, rows=rows), *args)
+    if not np.array_equal(np.asarray(got["grouped"]),
+                          np.asarray(got["scatter"])):
+        raise AssertionError("vertex chunk: grouped counts differ from the "
+                             "whole-mask scatter's")
+
+    @jax.jit
+    def mask(ss, sd, st, ct, nb):
+        u, v, s, _ = _gather_bucket_dev(ss, sd, st, ct, nb, n=n, e_pad=rows)
+        return intersect_matches(u, v), u, s
+
+    matched, u, s = jax.block_until_ready(mask(*args[:5]))
+    (_, _, runs), group_s = _best(jax.jit(engine._group_by_source),
+                                  matched, u, s)
+    log(f"  chunk: grouped {best['grouped']:.6f}s, whole-mask scatter "
+        f"{best['scatter']:.6f}s ({best['scatter'] / best['grouped']:.1f}x); "
+        f"grouping {int(runs)} runs {group_s:.6f}s; "
+        f"{int(got['grouped'].sum())} credits both")
 
 
 def phase_serve(scale: int, seed: int, requests: int = 8) -> None:

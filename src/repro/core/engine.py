@@ -371,28 +371,82 @@ def _build_hash_executable(backend: str, interpret: bool,
     return tracing.jit(name, run)
 
 
+# one block of the credit loop scatters about this many mask slots
+_CREDIT_BLOCK_SLOTS = 1 << 21
+
+
+def _cumsum_1d(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive int32 cumulative sum of the (E,) ``x``, taken along rows of
+    1,024 and then over the rows' totals. A one-level cumsum of 2^20
+    elements took ~36 s to compile for a v5e, this ~1 s."""
+    e, lanes = x.shape[0], 1024
+    c = jnp.cumsum(jnp.pad(x, (0, -e % lanes)).reshape(-1, lanes), axis=1,
+                   dtype=jnp.int32)
+    before = jnp.cumsum(c[:, -1]) - c[:, -1]
+    return (c + before[:, None]).reshape(-1)[:e]
+
+
+def _group_by_source(matched: jnp.ndarray, u_lists: jnp.ndarray,
+                     src: jnp.ndarray
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Sum the (E, W) match mask over each run of equal ``src`` among the
+    real rows (padding rows, u = -1, start no run).
+
+    Returns ``(grouped, firsts, runs)``: ``grouped`` (E, W) int32 holds the
+    ``runs`` run sums first and zero rows after, ``firsts`` (E,) each run's
+    first row. The sums are one sorted ``segment_sum`` of whole mask rows
+    over the rows' run ids; a padding row joins the run before it, which
+    its zero mask row leaves as it was."""
+    e = matched.shape[0]
+    rows = jnp.arange(e, dtype=jnp.int32)
+    real = u_lists[:, 0] >= 0
+    first = real & ((rows == 0) | (src != jnp.roll(src, 1))
+                    | ~jnp.roll(real, 1))
+    run = _cumsum_1d(first) - 1
+    grouped = jax.ops.segment_sum(matched.astype(jnp.int32), run,
+                                  num_segments=e, indices_are_sorted=True)
+    firsts = jnp.zeros(e, jnp.int32).at[jnp.where(first, run, e)].set(
+        rows, mode="drop")
+    return grouped, firsts, first.sum(dtype=jnp.int32)
+
+
 def _build_vertex_executable(n: int, name: str = "run") -> Callable:
     """Per-vertex triangle counts for one filtered-intersection bucket,
     added to ``acc``.
 
     ``intersect_matches`` (the mask form of the set-intersection core) marks
     which u-list entries appear in both forward neighbor lists; each match
-    (e, w) is one triangle (src[e], dst[e], w), so three segment_sums
-    attribute it to its three vertices. Padding never matches (disjoint u/v
-    sentinels), so the clip on the scatter ids is safe. ``acc`` is the (n,)
-    int32 sum of the pass so far (zeros where the host accumulates).
+    (e, j) is one triangle (src[e], dst[e], u_lists[e, j]). The high vertex
+    takes each edge's matches by a ``segment_sum`` over ``dst``. Every row
+    of a source has the same u row, N⁺(src), so the mask is first summed
+    over each run of equal ``src`` (``_group_by_source``): a bucket's rows
+    keep CSR order, so a W=512 chunk of 2^18 rows of the Graph500 scale-20
+    graph holds at most ~40k runs. A device loop over blocks of B runs,
+    B·W ≈ ``_CREDIT_BLOCK_SLOTS``, credits the third vertices at each run's
+    u row (the sentinel ``n`` is dropped) and the low vertex its run total.
+    Row order sets only the number of runs; the counts are exact for any
+    order. ``acc`` is the (n,) int32 sum of the pass so far (zeros where the
+    host accumulates).
     """
 
     def run(u_lists, v_lists, src, dst, acc):
         matched = intersect_matches(u_lists, v_lists)  # (E, W) bool
-        per_edge = matched.sum(axis=1, dtype=jnp.int32)
-        t = acc + jax.ops.segment_sum(per_edge, src, num_segments=n)
-        t = t + jax.ops.segment_sum(per_edge, dst, num_segments=n)
-        w_ids = jnp.clip(u_lists.reshape(-1), 0, n - 1)
-        t = t + jax.ops.segment_sum(
-            matched.reshape(-1).astype(jnp.int32), w_ids, num_segments=n
-        )
-        return t
+        t = acc + jax.ops.segment_sum(matched.sum(axis=1, dtype=jnp.int32),
+                                      dst, num_segments=n)
+        grouped, firsts, runs = _group_by_source(matched, u_lists, src)
+        e, w = u_lists.shape
+        block = min(e, max(1, _CREDIT_BLOCK_SLOTS // w))
+        if e % block:
+            grouped = jnp.pad(grouped, ((0, -e % block), (0, 0)))
+            firsts = jnp.pad(firsts, (0, -e % block))
+
+        def credit(i, t):
+            g = jax.lax.dynamic_slice_in_dim(grouped, i * block, block)
+            r = jax.lax.dynamic_slice_in_dim(firsts, i * block, block)
+            t = t.at[u_lists[r].reshape(-1)].add(g.reshape(-1), mode="drop")
+            return t.at[src[r]].add(g.sum(axis=1))
+
+        return jax.lax.fori_loop(0, (runs + block - 1) // block, credit, t)
 
     return tracing.jit(name, run)
 

@@ -144,7 +144,9 @@ class StreamedBucket:
     ``[offset, offset + rows)`` of the ``DeviceBucket`` layout it stands for
     — (u, v, src, dst), with rows past ``edges`` as whole-row padding —
     inside the executable that consumes them. One compiled gather serves
-    every chunk of every same-width bucket of a ``rows`` class.
+    every chunk of every same-width bucket of a ``rows`` class. The
+    bucket's rows ``[start, start + edges)`` of ``sorted_src`` keep CSR
+    order, so a chunk's rows come in runs of equal source.
     """
 
     width: int

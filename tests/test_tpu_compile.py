@@ -13,7 +13,9 @@ only the worker that runs this file may try.
 from __future__ import annotations
 
 import functools
+import math
 import os
+import re
 
 import pytest
 
@@ -139,6 +141,31 @@ def test_streamed_w128_chunk_gathers_without_a_loop(one_chip):
     assert not any("_gather_bucket_dev" in line for line in loops), loops
 
 
+_VERTEX_N = 1 << 20  # Graph500 scale 20, the LCC cell's graph
+
+
+@functools.lru_cache(maxsize=None)
+def _vertex_chunk(sharding, width: int):
+    """(compiled, chunk rows) of one streamed chunk's per-vertex
+    executable, ``tc_vertex_w<W>_gathered``, at Graph500 scale 20 as the
+    default budget streams it on a v5e."""
+    from repro.core import engine, prep
+
+    n = _VERTEX_N
+    chunk = engine._tile_chunk_rows(
+        1 << 24, prep.bucket_nbytes(1, width),
+        _V5E_HBM_BYTES // engine._BUCKET_MEMORY_SHARE)
+    run = prep.gathered_count(
+        engine._build_vertex_executable(n, f"tc_vertex_w{width}"),
+        endpoints=True)
+    edges = _spec(sharding, (1 << 24,), jnp.int32)
+    scalar = _spec(sharding, (), jnp.int32)
+    compiled = run.lower(
+        edges, edges, scalar, scalar, _spec(sharding, (n, width), jnp.int32),
+        _spec(sharding, (n,), jnp.int32), n=n, rows=chunk).compile()
+    return compiled, chunk
+
+
 @pytest.mark.parametrize("width", [512, 1024])
 def test_vertex_chunk_runs_the_broadcast_mask_at_scale_20(one_chip,
                                                           monkeypatch, width):
@@ -147,25 +174,52 @@ def test_vertex_chunk_runs_the_broadcast_mask_at_scale_20(one_chip,
     rule takes the row-chunked broadcast core on a TPU (no ``searchsorted``
     from the probe core), and its temporaries fit beside the neighbor
     tables the plan keeps (n × 4 B × (8 + 32 + 128 + 512 + 1024))."""
-    from repro.core import engine, prep
-
     # the rule asks JAX's default backend, which is the CPU here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n = 1 << 20
-    chunk = engine._tile_chunk_rows(
-        1 << 24, prep.bucket_nbytes(1, width),
-        _V5E_HBM_BYTES // engine._BUCKET_MEMORY_SHARE)
+    compiled, chunk = _vertex_chunk(one_chip, width)
     assert chunk == (1 << 18 if width == 512 else 1 << 17)
-    run = prep.gathered_count(
-        engine._build_vertex_executable(n, f"tc_vertex_w{width}"),
-        endpoints=True)
-    edges = _spec(one_chip, (1 << 24,), jnp.int32)
-    scalar = _spec(one_chip, (), jnp.int32)
-    compiled = run.lower(
-        edges, edges, scalar, scalar, _spec(one_chip, (n, width), jnp.int32),
-        _spec(one_chip, (n,), jnp.int32), n=n, rows=chunk).compile()
     text = compiled.as_text()
     assert "searchsorted" not in text and "reduce_or" in text
-    tables = n * 4 * (8 + 32 + 128 + 512 + 1024)
+    tables = _VERTEX_N * 4 * (8 + 32 + 128 + 512 + 1024)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert 0 < temp < _V5E_HBM_BYTES - tables - (1 << 30), temp
+
+
+def _scatter_updates(text: str):
+    """(scatter indices, whether it sits in a ``while`` body) of every
+    scatter in a compiled module's text: the elements of its updates over
+    those of one update window (1 for a scatter of scalars)."""
+    shapes = {}
+    for m in re.finditer(r"%([\w.-]+) = \w+\[([\d,]*)\]", text):
+        shapes[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+    out = []
+    for line in text.splitlines():
+        m = re.search(r" scatter\(([^)]*)\), update_window_dims=\{([\d,]*)\}",
+                      line)
+        if m:
+            updates = shapes[m.group(1).split(", ")[-1].lstrip("%")]
+            window = [int(d) for d in m.group(2).split(",") if d]
+            out.append((math.prod(d for i, d in enumerate(updates)
+                                  if i not in window),
+                        "/while/body/" in line))
+    return out
+
+
+def test_grouped_vertex_chunk_scatters_runs_not_mask_slots(one_chip,
+                                                           monkeypatch):
+    """The W=512 chunk of the LCC cell (2^18 rows, n = 2^20): no scatter
+    takes the chunk's 2^27 mask slots one by one (the grouping adds whole
+    rows); the credit loop's scatters take at most B·W slots a block;
+    nothing goes to or comes from the host."""
+    from repro.core import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, chunk = _vertex_chunk(one_chip, 512)
+    text = compiled.as_text()
+    scatters = _scatter_updates(text)
+    assert scatters and max(u for u, _ in scatters) < chunk * 512
+    in_loop = [u for u, body in scatters if body]
+    assert in_loop and max(in_loop) <= engine._CREDIT_BLOCK_SLOTS
+    for op in (" outfeed(", " infeed(", " send(", " recv(", "MoveToHost",
+               "callback"):
+        assert op not in text, op
