@@ -1,5 +1,3 @@
-"""One module per assigned architecture (+ the paper's own TC dataset config).
-
-Each exports CONFIG (the exact published configuration) and REDUCED (a
-same-family scale-down that one CPU core can forward/train-step in a smoke
-test)."""
+"""The paper's own experiment configuration (``paper.py``): the Table-1
+dataset registry keys and the per-figure benchmark settings that
+``benchmarks/run.py`` sweeps."""

@@ -1,14 +1,13 @@
-"""Multi-pod dry-run: prove every (arch × shape × mesh) cell lowers, compiles,
-shards coherently, and fits memory — without hardware.
+"""Multi-pod dry-run of the paper core: prove the planned
+``"matrix_distributed"`` lane lowers, compiles, shards coherently, and fits
+memory on the production meshes — without hardware.
 
 MUST set the placeholder-device flag before any other import (jax locks the
 device count on first init). Only this entrypoint sees 512 devices; tests and
 benches see 1.
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.dryrun --arch gemma2-2b --shape train_4k
-  PYTHONPATH=src python -m repro.launch.dryrun --all --out dryrun.jsonl
-  PYTHONPATH=src python -m repro.launch.dryrun --tc        # paper-core cell
+  PYTHONPATH=src python -m repro.launch.dryrun --tc
 """
 
 import os
@@ -20,7 +19,6 @@ os.environ["XLA_FLAGS"] = (
 
 # ruff: noqa: E402
 import argparse
-import functools
 import json
 import time
 import traceback
@@ -30,17 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import make_production_mesh
-from repro.launch.specs import (
-    SHAPES, abstract_params, cell_spec, input_specs, skip_reason,
-)
 from repro.launch.roofline import roofline_terms
-from repro.models.meshctx import activation_mesh
-from repro.models.registry import ARCHS, get_config, get_model
-from repro.train.optimizer import AdamWConfig, OptState, adamw_init
-from repro.train.sharding import (
-    batch_sharding, cache_specs, data_axis, param_shardings,
-)
-from repro.train.train_step import make_train_step
 
 
 def _cost_dict(compiled):
@@ -61,101 +49,6 @@ def _memory_dict(compiled):
             "temp_size_in_bytes", "generated_code_size_in_bytes",
             "alias_size_in_bytes"]
     return {k: int(getattr(m, k)) for k in keys if hasattr(m, k)}
-
-
-def _model_flops_per_chip(cfg, cell, chips: int) -> float:
-    n_active = cfg.active_param_count()
-    if cell.kind == "train":
-        tokens = cell.global_batch * cell.seq_len
-        return 6.0 * n_active * tokens / chips
-    if cell.kind == "prefill":
-        tokens = cell.global_batch * cell.seq_len
-        return 2.0 * n_active * tokens / chips
-    # decode: one token per sequence
-    return 2.0 * n_active * cell.global_batch / chips
-
-
-def lower_cell(arch: str, shape: str, mesh) -> dict:
-    """Lower + compile one cell; returns the §Dry-run/§Roofline record."""
-    cfg = get_config(arch)
-    cell = cell_spec(arch, shape)
-    chips = mesh.devices.size
-    rec = dict(arch=arch, shape=shape,
-               mesh="x".join(map(str, mesh.devices.shape)),
-               kind=cell.kind, chips=chips)
-    reason = skip_reason(cfg, shape)
-    if reason:
-        rec.update(status="skipped", reason=reason)
-        return rec
-
-    model = get_model(cfg)
-    with activation_mesh(mesh):
-        return _lower_cell_inner(arch, shape, mesh, cfg, cell, chips, rec,
-                                 model)
-
-
-def _lower_cell_inner(arch, shape, mesh, cfg, cell, chips, rec, model):
-    params_abs = abstract_params(arch)
-    p_shard = param_shardings(params_abs, mesh, fsdp=cfg.fsdp)
-    dax = data_axis(mesh)
-    t0 = time.time()
-
-    if cell.kind == "train":
-        opt_cfg = AdamWConfig(
-            moment_dtype=jnp.bfloat16 if cfg.adam_dtype == "bfloat16"
-            else jnp.float32)
-        opt_abs = jax.eval_shape(
-            functools.partial(adamw_init, cfg=opt_cfg), params_abs)
-        opt_shard = OptState(
-            step=NamedSharding(mesh, P()),
-            mu=jax.tree.map(lambda s: s, p_shard),
-            nu=jax.tree.map(lambda s: s, p_shard),
-        )
-        batch_abs = input_specs(arch, shape)
-        b_shard = {k: batch_sharding(mesh, v) for k, v in batch_abs.items()}
-        step = make_train_step(model, cfg, opt_cfg)
-        jitted = jax.jit(
-            step,
-            in_shardings=(p_shard, opt_shard, b_shard),
-            out_shardings=(p_shard, opt_shard, NamedSharding(mesh, P())),
-            donate_argnums=(0, 1),
-        )
-        lowered = jitted.lower(params_abs, opt_abs, batch_abs)
-    elif cell.kind == "prefill":
-        batch_abs = input_specs(arch, shape)
-        b_shard = {k: batch_sharding(mesh, v) for k, v in batch_abs.items()}
-        # VLM caches cover vision prefix + text
-        max_len = cell.seq_len + (cfg.vision_tokens if cfg.family == "vlm"
-                                  else 0)
-        fn = lambda params, batch: model.prefill(params, batch, max_len)
-        jitted = jax.jit(fn, in_shardings=(p_shard, b_shard))
-        lowered = jitted.lower(params_abs, batch_abs)
-    else:  # decode
-        specs = input_specs(arch, shape)
-        cache_abs, tok_abs = specs["cache"], specs["tokens"]
-        c_shard = cache_specs(cache_abs, mesh, cell.global_batch)
-        t_shard = batch_sharding(mesh, tok_abs)
-        jitted = jax.jit(model.decode_step,
-                         in_shardings=(p_shard, c_shard, t_shard),
-                         donate_argnums=(1,))
-        lowered = jitted.lower(params_abs, cache_abs, tok_abs)
-
-    t_lower = time.time() - t0
-    t0 = time.time()
-    compiled = lowered.compile()
-    t_compile = time.time() - t0
-
-    cost = _cost_dict(compiled)
-    mem = _memory_dict(compiled)
-    hlo = compiled.as_text()
-    rl = roofline_terms(
-        cost, hlo, model_flops_per_chip=_model_flops_per_chip(cfg, cell, chips))
-    rec.update(
-        status="ok", lower_s=round(t_lower, 2), compile_s=round(t_compile, 2),
-        memory=mem, roofline=rl.as_dict(),
-        params_b=cfg.param_count(), active_params_b=cfg.active_param_count(),
-    )
-    return rec
 
 
 def lower_tc(mesh, *, tiles: int = 8192, block: int = 128) -> dict:
@@ -192,14 +85,13 @@ def lower_tc(mesh, *, tiles: int = 8192, block: int = 128) -> dict:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--shape", default=None)
-    ap.add_argument("--all", action="store_true")
     ap.add_argument("--tc", action="store_true")
     ap.add_argument("--mesh", choices=["single", "multi", "both"],
                     default="both")
     ap.add_argument("--out", default=None, help="append JSONL here")
     args = ap.parse_args()
+    if not args.tc:
+        ap.error("--tc required")
 
     meshes = []
     if args.mesh in ("single", "both"):
@@ -207,35 +99,21 @@ def main():
     if args.mesh in ("multi", "both"):
         meshes.append(make_production_mesh(multi_pod=True))
 
-    cells = []
-    if args.tc:
-        cells = [("tc", None)]
-    elif args.all:
-        cells = [(a, s) for a in ARCHS for s in SHAPES]
-    else:
-        assert args.arch, "--arch, --all, or --tc required"
-        shapes = [args.shape] if args.shape else list(SHAPES)
-        cells = [(args.arch, s) for s in shapes]
-
     failures = 0
-    for arch, shape in cells:
-        for mesh in meshes:
-            try:
-                if arch == "tc":
-                    rec = lower_tc(mesh)
-                else:
-                    rec = lower_cell(arch, shape, mesh)
-            except Exception as e:  # a dry-run failure is a bug: report it
-                failures += 1
-                rec = dict(arch=arch, shape=shape,
-                           mesh="x".join(map(str, mesh.devices.shape)),
-                           status="error", error=repr(e),
-                           trace=traceback.format_exc()[-2000:])
-            line = json.dumps(rec)
-            print(line, flush=True)
-            if args.out:
-                with open(args.out, "a") as f:
-                    f.write(line + "\n")
+    for mesh in meshes:
+        try:
+            rec = lower_tc(mesh)
+        except Exception as e:  # a dry-run failure is a bug: report it
+            failures += 1
+            rec = dict(arch="tc", shape=None,
+                       mesh="x".join(map(str, mesh.devices.shape)),
+                       status="error", error=repr(e),
+                       trace=traceback.format_exc()[-2000:])
+        line = json.dumps(rec)
+        print(line, flush=True)
+        if args.out:
+            with open(args.out, "a") as f:
+                f.write(line + "\n")
     raise SystemExit(1 if failures else 0)
 
 

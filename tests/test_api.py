@@ -27,6 +27,7 @@ from repro.core import (
     triangle_count_scipy,
 )
 import repro.core.listing as listing
+from test_tc_algorithms import GRAPHS
 
 
 G_SKEWED = rmat_graph(8, 8, seed=41)  # scale-free: high degree skew
@@ -280,14 +281,16 @@ def test_count_many_mesh_fallback_warns_and_stays_correct():
 
 # --- per-vertex analysis through the cached plan ----------------------------
 
+@pytest.mark.parametrize("g", [G_SKEWED] + GRAPHS, ids=lambda g: g.name)
 @pytest.mark.parametrize("opts", [
     CountOptions(algorithm="intersection"),
     CountOptions(algorithm="subgraph"),
+    CountOptions(algorithm="bfs"),
     CountOptions(algorithm="matrix", block=32),  # sidecar fallback
+    CountOptions(algorithm="hash"),  # sidecar fallback
     CountOptions(algorithm="intersection", variant="full"),  # sidecar
 ], ids=lambda o: f"{o.algorithm}-{o.variant}")
-def test_vertex_analysis_matches_listing(opts):
-    g = G_SKEWED
+def test_vertex_analysis_matches_listing(opts, g):
     tc = TriangleCounter(g, opts)
     assert np.array_equal(tc.triangles_per_vertex(),
                           listing.triangles_per_vertex(g))

@@ -1,6 +1,5 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs the jnp oracle."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,9 +9,6 @@ from repro.kernels.intersect import (
     intersect_counts_probe_pallas, intersect_counts_ref,
 )
 from repro.kernels.masked_spgemm import masked_spgemm_pallas, masked_spgemm_ref
-from repro.kernels.flash_attention import (
-    flash_attention_pallas, flash_attention_ref,
-)
 
 
 # ------------------------------------------------------------- intersect
@@ -75,38 +71,3 @@ def test_masked_spgemm_pallas_matches_ref(t, b, dtype):
     rtol = 1e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal), rtol=rtol,
                                atol=1e-3)
-
-
-# -------------------------------------------------------- flash attention
-
-@pytest.mark.parametrize("b,s,hq,hkv,hd,causal,window,cap", [
-    (2, 64, 4, 2, 16, True, None, None),
-    (1, 128, 8, 1, 32, True, 32, 50.0),
-    (2, 64, 4, 4, 16, False, None, None),
-    (1, 256, 2, 1, 64, True, None, None),
-    (1, 64, 4, 2, 16, True, 16, None),
-])
-def test_flash_pallas_matches_ref(b, s, hq, hkv, hd, causal, window, cap):
-    ks = jax.random.split(jax.random.key(s + hq + hd), 3)
-    q = jax.random.normal(ks[0], (b, s, hq, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, hkv, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, hkv, hd), jnp.float32)
-    ref = flash_attention_ref(q, k, v, causal=causal, window=window, cap=cap)
-    pal = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 cap=cap, block_q=32, block_k=32,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(pal), rtol=2e-3,
-                               atol=2e-3)
-
-
-def test_flash_bf16():
-    ks = jax.random.split(jax.random.key(9), 3)
-    q = jax.random.normal(ks[0], (1, 64, 4, 32), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (1, 64, 2, 32), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, 64, 2, 32), jnp.bfloat16)
-    ref = flash_attention_ref(q, k, v)
-    pal = flash_attention_pallas(q, k, v, block_q=32, block_k=32,
-                                 interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(ref, np.float32), np.asarray(pal, np.float32),
-        rtol=5e-2, atol=5e-2)

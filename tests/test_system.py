@@ -1,10 +1,6 @@
 """End-to-end behaviour tests for the paper's system."""
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.graphs import load_dataset, rmat_graph
+from repro.graphs import load_dataset
 from repro.core import CountOptions, TriangleCounter, triangle_count_scipy
 
 
@@ -22,47 +18,3 @@ def test_end_to_end_all_methods_on_datasets():
         auto = TriangleCounter(g).count()
         assert auto == truth
         assert auto.algorithm in ("intersection", "matrix", "subgraph")
-
-
-def test_serving_loop_end_to_end():
-    """prefill → N greedy decode steps through the public serve API."""
-    from repro.models.registry import get_model, get_reduced_config
-    from repro.train.serve_step import greedy_generate
-
-    cfg = get_reduced_config("gemma2-2b")
-    model = get_model(cfg)
-    params = model.init(jax.random.key(0), dtype=jnp.float32)
-    prompts = jax.random.randint(jax.random.key(1), (2, 8), 0, cfg.vocab)
-    out = jax.jit(lambda p, b: greedy_generate(
-        model, cfg, p, b, steps=4, max_len=16))(params, {"tokens": prompts})
-    assert out.shape == (2, 4)
-    assert bool((out >= 0).all()) and bool((out < cfg.vocab).all())
-
-
-def test_train_then_serve_roundtrip():
-    """Train a few steps, checkpoint, restore, decode — the full lifecycle."""
-    import tempfile
-
-    from repro.models.registry import get_model, get_reduced_config
-    from repro.train.checkpoint import restore_checkpoint, save_checkpoint
-    from repro.train.data import SyntheticDataConfig, make_batch
-    from repro.train.optimizer import AdamWConfig
-    from repro.train.train_step import init_train_state, make_train_step
-
-    cfg = get_reduced_config("minicpm-2b")
-    model = get_model(cfg)
-    opt_cfg = AdamWConfig(peak_lr=1e-3, warmup_steps=2,
-                          moment_dtype=jnp.float32)
-    params, opt = init_train_state(model, cfg, opt_cfg, jax.random.key(0),
-                                   dtype=jnp.float32)
-    step = jax.jit(make_train_step(model, cfg, opt_cfg, microbatches=1))
-    for i in range(3):
-        batch = {k: jnp.asarray(v) for k, v in make_batch(
-            cfg, SyntheticDataConfig(4, 17), i).items()}
-        params, opt, metrics = step(params, opt, batch)
-    with tempfile.TemporaryDirectory() as d:
-        save_checkpoint(d, 3, {"params": params})
-        restored, _ = restore_checkpoint(d, 3, {"params": params})
-    logits, cache = jax.jit(lambda p, b: model.prefill(p, b, 24))(
-        restored["params"], {"tokens": batch["tokens"][:, :12]})
-    assert bool(jnp.isfinite(logits).all())

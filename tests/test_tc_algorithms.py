@@ -86,3 +86,18 @@ def test_subgraph_prune_stats_mesh_graph():
     assert stats["prune_fraction"] > 0.2  # leaf spurs pruned
     assert stats["edges_after"] < stats["edges_before"]
     assert stats["num_embeddings"] == 6 * count
+
+
+def test_labeled_subgraph_match():
+    from repro.core import subgraph_match_triangle
+    from repro.graphs.formats import edges_to_csr
+
+    # triangle 0-1-2 labeled (0,1,2) + triangle 3-4-5 labeled (0,0,0)
+    g = edges_to_csr(np.array([0, 1, 2, 3, 4, 5]),
+                     np.array([1, 2, 0, 4, 5, 3]), n=6)
+    labels = np.array([0, 1, 2, 0, 0, 0])
+    # ordered embeddings of labeled triangle (0,1,2): exactly one per
+    # orientation of the 0-1 edge = 1 (u=0,v=1,w=2)
+    assert subgraph_match_triangle(g, labels, (0, 1, 2)) == 1
+    assert subgraph_match_triangle(g, labels, (0, 0, 0)) == 6  # all perms
+    assert subgraph_match_triangle(g, labels, (2, 2, 2)) == 0

@@ -16,6 +16,8 @@ accumulation). This module is the differential harness:
   per (chunk, width) then pure replays);
 * ``triangles_per_vertex`` over a tiled filtered plan (the vertex
   executable streams the same chunks);
+* chunks past a device bucket's real rows hold only padding and leave the
+  count exact;
 * budget semantics: a budget big enough for everything tiles nothing and
   keys a distinct plan from the unbudgeted options;
 * device prep gathers every bucket, resident or streamed, as whole rows of
@@ -94,6 +96,38 @@ def test_tiled_subgraph(g_er):
     tiled = _count(g_er, algorithm="subgraph", max_device_bytes=1 << 13)
     assert int(tiled) == oracle
     assert tiled.meta["num_chunks"] >= 2
+
+
+def _padding_only_chunks(meta) -> int:
+    """Chunks of the streamed buckets' padded extents that start past the
+    buckets' real rows, whether or not the plan dispatches them."""
+    total = 0
+    for tb in meta["tiled_buckets"]:
+        rows, chunk = tb["shape"][0], tb["chunk_rows"]
+        edges = meta["bucket_edges"][meta["bucket_shapes"].index(tb["shape"])]
+        total += -(-rows // chunk) - -(-edges // chunk)
+    return total
+
+
+# g_er's W=8 bucket holds 1,936 real rows and its W=32 bucket 37; device
+# prep pads them to 2,048 and 64 rows, and each budget picks the chunk rows
+# that leave this many whole chunks of padding past them
+@pytest.mark.parametrize("budget,padding_chunks",
+                         [(1 << 14, 0), (8704, 1), (1 << 12, 6)])
+@pytest.mark.parametrize("prep_backend", ["device", "host"])
+def test_padding_only_chunks_leave_the_count_exact(g_er, prep_backend,
+                                                   budget, padding_chunks):
+    """Chunks that hold only padding add nothing: the streamed count equals
+    the untiled plan and scipy. Host prep keeps only a bucket's real rows
+    (its tail chunk is padded on upload), so its plan has no such chunk."""
+    oracle = int(triangle_count_scipy(g_er))
+    mono = _count(g_er, algorithm="intersection", prep_backend=prep_backend)
+    tiled = _count(g_er, algorithm="intersection", prep_backend=prep_backend,
+                   max_device_bytes=budget)
+    assert tiled.meta["tiled_buckets"], tiled.meta
+    assert _padding_only_chunks(tiled.meta) == (
+        padding_chunks if prep_backend == "device" else 0)
+    assert int(tiled) == int(mono) == oracle
 
 
 def test_tiled_steady_state_never_recompiles(g_rmat):

@@ -350,3 +350,19 @@ def test_full_dataset_truss_agreement(name):
     for k in (4, 6):
         _assert_same_graph(tc.k_truss(k), listing._k_truss_host(g, k),
                            (name, k))
+
+
+def test_listing_and_truss():
+    from repro.core import (clustering_coefficients, k_truss, transitivity,
+                            enumerate_triangles)
+    from repro.graphs import complete_graph, grid_graph
+
+    k4 = complete_graph(4)
+    assert enumerate_triangles(k4).shape == (4, 3)
+    np.testing.assert_allclose(clustering_coefficients(k4), np.ones(4))
+    assert transitivity(k4) == pytest.approx(1.0)
+    # k-truss of K4 at k=4: every edge in 2 triangles ⇒ survives; k=5 empty
+    assert k_truss(k4, 4).m_undirected == 6
+    assert k_truss(k4, 5).m_undirected == 0
+    g = grid_graph(8, seed=0)
+    assert k_truss(g, 3).m_undirected <= g.m_undirected
